@@ -53,6 +53,8 @@ def main() -> None:
                     help="also write rows as a JSON snapshot")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.kernels import plan as plan_mod
     if args.pin_config:
         bm, bn, bk = (int(v) for v in args.pin_config.lower().split("x"))

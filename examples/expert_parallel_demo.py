@@ -17,14 +17,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.moe import (MoEConfig, init_moe_params, moe_apply,
                             shard_moe_params)
 
 
 def main():
     assert len(jax.devices()) >= 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = MoEConfig(num_experts=8, top_k=2, d_model=256, d_ff_expert=128,
                     num_shared_experts=1, capacity_factor=8.0)
     params = init_moe_params(jax.random.PRNGKey(0), cfg)
@@ -45,9 +45,9 @@ def main():
                            ep_size=ep, axis_name="model")
         return y.reshape(b, s, d)
 
-    fn = jax.jit(shard_map(local_fn, mesh=mesh,
-                           in_specs=(pspecs, xspec), out_specs=xspec,
-                           check_vma=False))
+    fn = jax.jit(jax.shard_map(local_fn, mesh=mesh,
+                               in_specs=(pspecs, xspec), out_specs=xspec,
+                               check_vma=False))
     y_ep = fn(params, x)
 
     err = float(jnp.max(jnp.abs(y_ep - y_ref)))

@@ -2,9 +2,9 @@
 # Tier-1 regression gate: the full suite on CPU.
 #
 # Runs everywhere (no accelerator needed): the Pallas kernels execute in
-# interpret mode, TPU-only backends are refused via capability probes (and
-# their tests select CPU-runnable backends), and repro.compat absorbs JAX
-# API drift across the supported range (see README.md).
+# interpret mode, TPU-only backends are refused via the TPU probe (and
+# their tests select CPU-runnable backends), and tests/test_tpu_compile.py
+# compiles the main kernels for a described TPU v5e.
 #
 #   scripts/ci_tier1.sh [extra pytest args]
 set -euo pipefail

@@ -1,193 +1,17 @@
-"""JAX version-adaptation layer.
+"""Platform probe.
 
-The repo targets a range of JAX releases whose APIs drifted in three places
-the kernels and models care about:
-
-  * ``shard_map``      — ``jax.experimental.shard_map.shard_map`` (<= 0.4.x,
-                         ``check_rep=`` kwarg) vs ``jax.shard_map``
-                         (>= 0.5, ``check_vma=`` kwarg).
-  * Pallas TPU params  — ``pltpu.TPUCompilerParams`` (<= 0.4.x) vs
-                         ``pltpu.CompilerParams`` (newer releases).
-  * ragged contraction — ``jax.lax.ragged_dot_general`` +
-                         ``RaggedDotDimensionNumbers`` (newer releases) vs
-                         plain ``jax.lax.ragged_dot`` only (0.4.x).
-
-Everything version-dependent is resolved HERE, once, at import time; the
-rest of the codebase imports the resolved name and never touches
-``jax.experimental`` feature detection again.  Capability *probes*
-(``has_tpu()``, ``has_ragged_dot_general()``, ...) are plain functions so
-tests can monkeypatch them to exercise every dispatch branch on any box.
+The kernels and models are written against the installed JAX (0.9): they
+use ``jax.shard_map``, ``pltpu.CompilerParams``, ``jax.lax.ragged_dot``
+and ``jax.lax.ragged_dot_general`` directly.  What remains here is the
+one question the dispatch registry asks of the platform, kept as a plain
+function so tests can monkeypatch it to exercise every dispatch branch on
+any box.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any
-
 import jax
-import jax.numpy as jnp
 
-
-# ---------------------------------------------------------------------------
-# Capability probes (monkeypatchable; keep them trivial)
-# ---------------------------------------------------------------------------
 
 def has_tpu() -> bool:
     """True iff the default JAX backend is a real TPU (compiled Pallas)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
-def has_ragged_dot() -> bool:
-    return hasattr(jax.lax, "ragged_dot")
-
-
-def has_ragged_dot_general() -> bool:
-    return (hasattr(jax.lax, "ragged_dot_general")
-            and hasattr(jax.lax, "RaggedDotDimensionNumbers"))
-
-
-def has_shard_map_in_jax() -> bool:
-    """``jax.shard_map`` was promoted out of ``jax.experimental`` in 0.5."""
-    return hasattr(jax, "shard_map")
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-def _resolve_shard_map():
-    if has_shard_map_in_jax():
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
-_shard_map_impl = _resolve_shard_map()
-_shard_map_kwargs = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None,
-              **kwargs):
-    """Version-stable ``shard_map``.
-
-    Callers use the modern spelling (``check_vma=``); on JAX 0.4.x the flag
-    is forwarded as ``check_rep`` (same meaning: verify that outputs marked
-    replicated really are).
-    """
-    if check_vma is not None:
-        if "check_vma" in _shard_map_kwargs:
-            kwargs["check_vma"] = check_vma
-        else:
-            kwargs["check_rep"] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params
-# ---------------------------------------------------------------------------
-
-def _resolve_tpu_compiler_params():
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls
-
-
-# The resolved class: construct with the same kwargs on every JAX release
-# (e.g. ``TPUCompilerParams(dimension_semantics=(...,))``).
-TPUCompilerParams = _resolve_tpu_compiler_params()
-
-
-def tpu_compiler_params(**kwargs) -> Any:
-    return TPUCompilerParams(**kwargs)
-
-
-# ---------------------------------------------------------------------------
-# Compiled-artifact introspection
-# ---------------------------------------------------------------------------
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a one-element *list* of dicts
-    on JAX 0.4.x and a plain dict on newer releases; normalize to a dict
-    (empty when XLA provides nothing)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
-# ---------------------------------------------------------------------------
-# Ragged contractions
-# ---------------------------------------------------------------------------
-
-def ragged_dot(lhs, rhs, group_sizes, *, preferred_element_type=None):
-    """``y[i, n] = sum_k lhs[i, k] * rhs[group_of(i), k, n]`` over the
-    concatenated ragged row buffer.  Passthrough on every supported JAX;
-    a dense gather fallback (memory-heavy, correctness-only) covers
-    hypothetical builds without ``jax.lax.ragged_dot``.
-    """
-    gs = group_sizes.astype(jnp.int32)
-    if has_ragged_dot():
-        return jax.lax.ragged_dot(
-            lhs, rhs, gs, preferred_element_type=preferred_element_type)
-    m = lhs.shape[0]
-    g = rhs.shape[0]
-    seg = jnp.repeat(jnp.arange(g, dtype=jnp.int32), gs,
-                     total_repeat_length=m)
-    return jnp.einsum("mk,mkn->mn", lhs, rhs[seg],
-                      preferred_element_type=preferred_element_type)
-
-
-def ragged_wgrad(x, dy, group_sizes, *, num_groups: int):
-    """Grouped weight gradient ``dw[g] = x_g^T @ dy_g`` (f32 accumulation)
-    over the ragged contracting (row) dimension.
-
-    Two equivalent formulations, picked by capability:
-
-      * ``ragged_dot_general`` with ``lhs_ragged_dimensions=[0]`` and the
-        rows as contracting dims — the direct spelling (JAX >= 0.5-era).
-      * transpose-of-``ragged_dot``: since ``y = ragged_dot(x, w, gs)`` is
-        linear in ``w``, its VJP at cotangent ``dy`` IS exactly
-        ``dw[g] = x_g^T @ dy_g``.  ``jax.vjp`` pulls that transpose out of
-        the existing primitive, so JAX 0.4.x needs nothing beyond
-        ``ragged_dot`` itself.
-
-    ``tests/test_compat_dispatch.py`` pins numerical agreement between the
-    two formulations (and both against a dense one-hot oracle).
-    """
-    if has_ragged_dot_general():
-        dn = jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=[0],
-            rhs_group_dimensions=[])
-        return jax.lax.ragged_dot_general(
-            x, dy, group_sizes.astype(jnp.int32), dn,
-            preferred_element_type=jnp.float32)
-    return _ragged_wgrad_via_transpose(x, dy, group_sizes,
-                                       num_groups=num_groups)
-
-
-def _ragged_wgrad_via_transpose(x, dy, group_sizes, *, num_groups: int):
-    if not has_ragged_dot():
-        raise NotImplementedError(
-            "ragged_wgrad needs jax.lax.ragged_dot_general or "
-            f"jax.lax.ragged_dot; neither exists in jax {jax.__version__}")
-    k, n = x.shape[1], dy.shape[1]
-    gs = group_sizes.astype(jnp.int32)
-    # f32 operands reproduce ragged_dot_general's semantics exactly: the
-    # callers pre-round x/dy to bf16, and preferred_element_type=f32 means
-    # products/accumulation happen in f32 either way.
-    xf = x.astype(jnp.float32)
-    w0 = jax.ShapeDtypeStruct((num_groups, k, n), jnp.float32)
-    # linear_transpose (not vjp): the map is linear in w, and this skips
-    # evaluating a throwaway forward ragged_dot against zero weights
-    transpose = jax.linear_transpose(
-        lambda w: jax.lax.ragged_dot(
-            xf, w, gs, preferred_element_type=jnp.float32), w0)
-    (dw,) = transpose(dy.astype(jnp.float32))
-    return dw
+    return jax.default_backend() == "tpu"

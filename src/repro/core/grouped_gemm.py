@@ -62,8 +62,8 @@ def _ragged_dot(x, w, group_sizes, out_dtype):
 def _wgrad(x, dy, group_sizes, num_groups, *, config=None, plan=None):
     """dw[g] = x_g^T @ dy_g — ragged contracting dim, bf16 operands / f32
     accumulation, through the wgrad dispatch registry (the padding-free
-    kernel where available; ``compat.ragged_wgrad`` is the registry's
-    ``xla_ragged`` fallback, no longer the only path)."""
+    kernel where available; ``ragged_dot_general`` is the registry's
+    ``xla_ragged`` fallback)."""
     return dispatch.grouped_gemm_wgrad(
         x.astype(jnp.bfloat16), dy.astype(jnp.bfloat16), group_sizes,
         num_groups=num_groups, config=config, out_dtype=jnp.float32,

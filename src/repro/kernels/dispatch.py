@@ -44,7 +44,7 @@ through ``(wgrad, <precision>)``, activation quantization through
 through all of them.  Each entry is a :class:`BackendSpec` with
 
   * an ``available()`` probe returning ``(ok, reason)`` — built on
-    :mod:`repro.compat` capability probes so selection is testable by
+    the :mod:`repro.compat` TPU probe so selection is testable by
     monkeypatching, and refusal is an explicit
     :class:`BackendUnavailableError` instead of a deep ``AttributeError``;
   * a ``run()`` implementing the family's operation under a
@@ -83,6 +83,7 @@ seam — new backends, precisions, and op families plug in via
 from __future__ import annotations
 
 import dataclasses
+import warnings
 from typing import Any, Callable, Optional
 
 import jax
@@ -280,12 +281,21 @@ def resolve(op_key, backend: Optional[str] = None, *,
 
 
 def _tile_policy(key: OpKey, name: str, tile, *, explicit: bool) -> str:
-    """Shared tile-incompatibility policy: see :func:`resolve`."""
-    if tile is None:
-        return name
+    """Shared tile-incompatibility policy (see :func:`resolve`); every
+    resolution ends here and is emitted as a ``backend_resolved`` event."""
+    if tile is not None and _OPERATORS[key][name].uses_plan:
+        name = _tile_fallback(key, name, tile, explicit=explicit)
+    _events.emit("backend_resolved", family=key.family,
+                 precision=key.precision, backend=name)
+    return name
+
+
+#: (family, precision, m, k, n) shapes whose TPU tile fallback was warned
+_FALLBACK_WARNED: "set[tuple]" = set()
+
+
+def _tile_fallback(key: OpKey, name: str, tile, *, explicit: bool) -> str:
     table = _OPERATORS[key]
-    if not table[name].uses_plan:
-        return name
     cfg, m, k, n = tile
     if cfg.compatible(k, n, family=key.family):
         return name
@@ -294,6 +304,14 @@ def _tile_policy(key: OpKey, name: str, tile, *, explicit: bool) -> str:
         cfg.validate(m, k, n, family=key.family)
     for fb in ("xla_ragged", "xla_exact"):
         if fb in table and table[fb].available()[0]:
+            shape = (key.family, key.precision, m, k, n)
+            if compat.has_tpu() and shape not in _FALLBACK_WARNED:
+                _FALLBACK_WARNED.add(shape)
+                warnings.warn(
+                    f"{key.family}/{key.precision} at (M={m}, K={k}, N={n}): "
+                    f"{name!r} tiles {cfg.effective_blocks(key.family)} do "
+                    f"not divide (K, N); running {fb!r} instead",
+                    stacklevel=3)
             return fb
     eff_k, eff_n = cfg.effective_blocks(key.family)
     raise BackendUnavailableError(
@@ -365,6 +383,8 @@ def format_backend_matrix() -> str:
             needs = "—" if row["available"] else row["reason"].split(";")[0]
             if name == "pallas":
                 needs = "TPU"
+            elif name == "pallas_interpret":
+                needs = "no TPU"
             lines.append(f"| `{family}` | `{precision}` | `{disp}` | "
                          f"{needs} | {row['description']} |")
     return "\n".join(lines)
@@ -516,8 +536,8 @@ def gmm_xla(a_fp8, s_a, b_fp8, s_b, group_sizes, *, out_dtype=jnp.bfloat16,
     """ragged_dot on dequantized operands (GSPMD-partitionable)."""
     a = _dequant_a(a_fp8, s_a, compute_dtype)
     b = _dequant_b(b_fp8, s_b, compute_dtype)
-    out = compat.ragged_dot(a, b, group_sizes.astype(jnp.int32),
-                            preferred_element_type=jnp.float32)
+    out = jax.lax.ragged_dot(a, b, group_sizes.astype(jnp.int32),
+                             preferred_element_type=jnp.float32)
     return out.astype(out_dtype)
 
 
@@ -535,8 +555,8 @@ def gmm_xla_exact(a_fp8, s_a, b_fp8, s_b, group_sizes, *,
     for j in range(kb):
         aj = a_fp8[:, j * QUANT_BLOCK:(j + 1) * QUANT_BLOCK].astype(jnp.float32)
         bj = b_fp8[:, j * QUANT_BLOCK:(j + 1) * QUANT_BLOCK, :].astype(jnp.float32)
-        part = compat.ragged_dot(aj, bj, gs,
-                                 preferred_element_type=jnp.float32)
+        part = jax.lax.ragged_dot(aj, bj, gs,
+                                  preferred_element_type=jnp.float32)
         # gather this token's group column-scales: expand s_b rows per group
         seg = jnp.repeat(jnp.arange(g), gs, total_repeat_length=m)
         col = jnp.repeat(s_b[:, j, :], QUANT_BLOCK, axis=1)[:, :n]   # (g, n)
@@ -579,11 +599,17 @@ def gmm_bf16_xla_exact(x, w, group_sizes, *, out_dtype=jnp.bfloat16):
 
 def wgrad_xla_ragged(x, dy, group_sizes, *, num_groups,
                      out_dtype=jnp.float32):
-    """``compat.ragged_wgrad``: ``ragged_dot_general`` where available,
-    transpose-of-``ragged_dot`` otherwise — the historical wgrad path,
+    """``jax.lax.ragged_dot_general`` with the rows (the ragged dim) as
+    the contracting dims, f32 accumulation — the historical wgrad path,
     now the portable fallback of this family."""
-    return compat.ragged_wgrad(x, dy, group_sizes,
-                               num_groups=num_groups).astype(out_dtype)
+    dn = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0],
+        rhs_group_dimensions=[])
+    dw = jax.lax.ragged_dot_general(
+        x, dy, group_sizes.astype(jnp.int32), dn,
+        preferred_element_type=jnp.float32)
+    return dw.astype(out_dtype)
 
 
 def wgrad_xla_exact(x, dy, group_sizes, *, num_groups,
@@ -643,17 +669,11 @@ def _avail_tpu():
                    "use 'pallas_interpret' for CPU-verifiable runs")
 
 
-def _avail_ragged_dot():
-    if compat.has_ragged_dot():
+def _avail_interpret():
+    if not compat.has_tpu():
         return True, ""
-    return False, (f"jax {jax.__version__} has no jax.lax.ragged_dot")
-
-
-def _avail_ragged_wgrad():
-    if compat.has_ragged_dot_general() or compat.has_ragged_dot():
-        return True, ""
-    return False, (f"jax {jax.__version__} has neither "
-                   "jax.lax.ragged_dot_general nor jax.lax.ragged_dot")
+    return False, ("interpret mode is the CPU verification path; "
+                   "on a TPU use the compiled 'pallas' entry")
 
 
 # ---- (gemm, fp8): the paper's forward/dgrad orientation -------------------
@@ -694,20 +714,20 @@ register_operator(
     ("gemm", "fp8"), "pallas_interpret",
     description="Pallas kernel in interpret mode — CPU-verifiable, "
                 "bit-identical to 'pallas'",
-    available=_avail_always,
+    available=_avail_interpret,
     run=lambda *a, **kw: _run_pallas(*a, interpret=True, **kw),
     uses_plan=True, uses_tiles=True)
 register_operator(
     ("gemm", "fp8"), "xla_ragged",
     description="jax.lax.ragged_dot on bf16-dequantized operands "
                 "(portable / GSPMD)",
-    available=_avail_ragged_dot,
+    available=_avail_always,
     run=_run_xla_ragged)
 register_operator(
     ("gemm", "fp8"), "xla_exact",
     description="per-K-block f32 oracle with the kernel's accumulation "
                 "order",
-    available=_avail_ragged_dot,
+    available=_avail_always,
     run=_run_xla_exact)
 register_operator(
     ("gemm", "fp8"), "padded_baseline",
@@ -729,9 +749,9 @@ def _run_pallas_bf16(x, w, gs, *, num_groups, config, plan, interpret):
 
 
 def _run_bf16_ragged(x, w, gs, *, config, **_):
-    out = compat.ragged_dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-                            gs.astype(jnp.int32),
-                            preferred_element_type=jnp.float32)
+    out = jax.lax.ragged_dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                             gs.astype(jnp.int32),
+                             preferred_element_type=jnp.float32)
     return out.astype(config.out_dtype)
 
 
@@ -750,14 +770,13 @@ register_operator(
     ("gemm", "bf16"), "pallas_interpret",
     description="bf16 Pallas kernel in interpret mode — CPU-verifiable, "
                 "bit-identical to 'pallas'",
-    available=_avail_always,
+    available=_avail_interpret,
     run=lambda *a, **kw: _run_pallas_bf16(*a, interpret=True, **kw),
     uses_plan=True, uses_tiles=True)
 register_operator(
     ("gemm", "bf16"), "xla_ragged",
-    description="jax.lax.ragged_dot on bf16 operands (numerics baseline; "
-                "dense fallback where the primitive is missing)",
-    available=_avail_always,       # compat.ragged_dot always has a fallback
+    description="jax.lax.ragged_dot on bf16 operands (numerics baseline)",
+    available=_avail_always,
     run=_run_bf16_ragged)
 register_operator(
     ("gemm", "bf16"), "xla_exact",
@@ -809,20 +828,20 @@ register_operator(
     ("gemm_quant", "fp8"), "pallas_interpret",
     description="quantizing-epilogue kernel in interpret mode — "
                 "CPU-verifiable, bit-identical to 'pallas'",
-    available=_avail_always,
+    available=_avail_interpret,
     run=lambda *a, **kw: _run_gemm_quant_pallas(*a, interpret=True, **kw),
     uses_plan=True, uses_tiles=True)
 register_operator(
     ("gemm_quant", "fp8"), "xla_ragged",
     description="unfused composition: xla_ragged GEMM then reference "
                 "tilewise quantize",
-    available=_avail_ragged_dot,
+    available=_avail_always,
     run=_compose_gemm_quant("xla_ragged"))
 register_operator(
     ("gemm_quant", "fp8"), "xla_exact",
     description="unfused composition: xla_exact GEMM then reference "
                 "tilewise quantize",
-    available=_avail_ragged_dot,
+    available=_avail_always,
     run=_compose_gemm_quant("xla_exact"))
 register_operator(
     ("gemm_quant", "fp8"), "padded_baseline",
@@ -871,14 +890,13 @@ register_operator(
     ("wgrad", "bf16"), "pallas_interpret",
     description="wgrad kernel in interpret mode — CPU-verifiable, "
                 "bit-identical to 'pallas'",
-    available=_avail_always,
+    available=_avail_interpret,
     run=lambda *a, **kw: _run_pallas_wgrad(*a, interpret=True, **kw),
     uses_plan=True, uses_tiles=True)
 register_operator(
     ("wgrad", "bf16"), "xla_ragged",
-    description="compat.ragged_wgrad (ragged_dot_general or transposed "
-                "ragged_dot) — portable fallback",
-    available=_avail_ragged_wgrad,
+    description="jax.lax.ragged_dot_general — portable fallback",
+    available=_avail_always,
     run=_run_wgrad_xla_ragged)
 register_operator(
     ("wgrad", "bf16"), "xla_exact",
@@ -924,14 +942,14 @@ register_operator(
     ("wgrad", "fp8"), "pallas_interpret",
     description="fp8 wgrad kernel in interpret mode — CPU-verifiable, "
                 "bit-identical to 'pallas_fp8'",
-    available=_avail_always,
+    available=_avail_interpret,
     run=lambda *a, **kw: _run_pallas_wgrad_fp8(*a, interpret=True, **kw),
     uses_plan=True, uses_tiles=True)
 register_operator(
     ("wgrad", "fp8"), "xla_ragged",
-    description="up-front bf16 dequantization + compat.ragged_wgrad — "
+    description="up-front bf16 dequantization + ragged_dot_general — "
                 "portable fp8-operand fallback",
-    available=_avail_ragged_wgrad,
+    available=_avail_always,
     run=_run_wgrad_fp8_xla_ragged)
 register_operator(
     ("wgrad", "fp8"), "xla_exact",
@@ -963,18 +981,18 @@ register_operator(
     ("quantize", "fp8"), "pallas_interpret",
     description="quantizer kernel in interpret mode — CPU-verifiable, "
                 "bit-identical to 'pallas'",
-    available=_avail_always,
+    available=_avail_interpret,
     run=lambda *a, **kw: _run_quant_pallas(*a, interpret=True, **kw),
     uses_tiles=True)
 register_operator(
     ("quantize", "fp8"), "xla_ragged",
     description="XLA reference quantizer (tile shapes are a no-op)",
-    available=_avail_ragged_dot,
+    available=_avail_always,
     run=_run_quant_ref)
 register_operator(
     ("quantize", "fp8"), "xla_exact",
     description="XLA reference quantizer (tile shapes are a no-op)",
-    available=_avail_ragged_dot,
+    available=_avail_always,
     run=_run_quant_ref)
 register_operator(
     ("quantize", "fp8"), "padded_baseline",
@@ -1014,20 +1032,20 @@ register_operator(
     ("act_quant", "fp8"), "pallas_interpret",
     description="fused epilogue kernel in interpret mode — CPU-verifiable, "
                 "bit-identical to 'pallas'",
-    available=_avail_always,
+    available=_avail_interpret,
     run=lambda *a, **kw: _run_act_quant_pallas(*a, interpret=True, **kw),
     uses_tiles=True)
 register_operator(
     ("act_quant", "fp8"), "xla_ragged",
     description="unfused XLA reference: activation then tilewise quantize "
                 "(tile shapes are a no-op)",
-    available=_avail_ragged_dot,
+    available=_avail_always,
     run=_run_act_quant_ref)
 register_operator(
     ("act_quant", "fp8"), "xla_exact",
     description="unfused XLA reference: activation then tilewise quantize "
                 "(tile shapes are a no-op)",
-    available=_avail_ragged_dot,
+    available=_avail_always,
     run=_run_act_quant_ref)
 register_operator(
     ("act_quant", "fp8"), "padded_baseline",
@@ -1177,8 +1195,8 @@ def grouped_gemm_bf16(x, w, group_sizes, *, backend: Optional[str] = None,
     the numerics-baseline orientation ``grouped_linear(precision="bf16")``
     builds on.  A true Pallas kernel (the fp8 twin's visit schedule, bf16
     operands, f32 accumulate) leads the auto order on TPU;
-    ``jax.lax.ragged_dot`` (with a dense fallback) keeps the family
-    available on every JAX.  Same tile-fallback semantics as every other
+    ``jax.lax.ragged_dot`` keeps the family
+    available on every platform.  Same tile-fallback semantics as every other
     plan consumer: an auto-resolved kernel whose tile shapes don't divide
     (K, N) falls back to the tile-free entries, an explicit request
     raises.  Not differentiable — training goes through

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -121,7 +120,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 256,
             pltpu.VMEM((block_q, 1), jnp.float32),       # running denom
             pltpu.VMEM((block_q, d), jnp.float32),       # accumulator
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,

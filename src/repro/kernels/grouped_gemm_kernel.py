@@ -48,7 +48,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels.quant_kernel import FP8_MAX
 from repro.kernels.plan import (  # noqa: F401  (metadata lives in plan.py;
     QUANT_BLOCK,                   # re-exported here for pre-plan callers)
@@ -71,6 +70,16 @@ def validate_kernel_config(m, k, n, block_m, block_n, block_k):
                  block_k=block_k).validate(m, k, n)
 
 
+def select_index(s, idx, axis):
+    """``s`` narrowed to entry ``idx`` of ``axis`` (kept as a size-1 dim)
+    for a traced ``idx``.  Mosaic lowers no ``dynamic_slice`` of a value,
+    so an iota mask keeps the one entry and the sum adds only exact zeros
+    to it: the result is bitwise the sliced entry, whatever the others
+    (possibly garbage) hold."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
+    return jnp.sum(jnp.where(pos == idx, s, 0.0), axis=axis, keepdims=True)
+
+
 def _accumulate_visit(a_ref, sa_ref, b_ref, sb_ref, acc_ref, *,
                       n_i, k_i, block_m, block_n, block_k):
     """One visit's MXU work: the fine-grained-rescaled partial products of
@@ -84,18 +93,25 @@ def _accumulate_visit(a_ref, sa_ref, b_ref, sb_ref, acc_ref, *,
     b = b_ref[0].astype(jnp.float32)                   # (bk, bn)
 
     # --- fine-grained rescale (DeepSeek 1x128 x 128x128 recipe) ---------
-    # sa_ref: (bm, KB) over-fetched whole scale rows; columns for this k step
+    # sa_ref: (bm, KB) over-fetched whole scale rows; sb_ref: (1, KB, NB)
+    # the group's whole scale block.  This step's entries are picked by
+    # mask (select_index), never sliced at a traced offset.
     kq = block_k // QUANT_BLOCK                        # quant tiles per k step
     nq = block_n // QUANT_BLOCK                        # quant blocks per n step
-    sa = jax.lax.dynamic_slice(sa_ref[...], (0, k_i * kq), (block_m, kq))
-    sb = jax.lax.dynamic_slice(sb_ref[0], (k_i * kq, n_i * nq), (kq, nq))
+    sa_all = sa_ref[...]
+    sb_all = sb_ref[0]
     # one MXU dot per 128-wide quant sub-tile so per-tile scales stay exact
     for j in range(kq):
         aj = a[:, j * QUANT_BLOCK:(j + 1) * QUANT_BLOCK]
         bj = b[j * QUANT_BLOCK:(j + 1) * QUANT_BLOCK]
         pj = jax.lax.dot(aj, bj, preferred_element_type=jnp.float32)
-        col_scale = jnp.repeat(sb[j], QUANT_BLOCK, axis=0)     # (bn,)
-        acc_ref[...] += pj * sa[:, j][:, None] * col_scale[None, :]
+        sa_j = select_index(sa_all, k_i * kq + j, 1)          # (bm, 1)
+        sb_j = select_index(sb_all, k_i * kq + j, 0)          # (1, NB)
+        col_scale = jnp.concatenate(
+            [jnp.broadcast_to(select_index(sb_j, n_i * nq + q, 1),
+                              (1, QUANT_BLOCK)) for q in range(nq)],
+            axis=1)                                           # (1, bn)
+        acc_ref[...] += pj * sa_j * col_scale
 
 
 def _gmm_kernel(group_offsets_ref, group_ids_ref, m_tile_ids_ref,  # prefetch
@@ -234,7 +250,7 @@ def gmm_pallas(a_fp8: jax.Array, s_a: jax.Array, b_fp8: jax.Array,
                 scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
             ),
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             ),
             interpret=interpret,
@@ -368,7 +384,7 @@ def gmm_pallas_bf16(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
                 scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
             ),
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             ),
             interpret=interpret,
@@ -442,8 +458,8 @@ def _gmm_quant_kernel(group_offsets_ref, group_ids_ref, m_tile_ids_ref,
         q_ref[...] = jnp.where(
             owned, qv,
             jnp.where(unowned, jnp.zeros_like(qv), prev_q)).astype(q_ref.dtype)
-        prev_s = s_ref[...]
-        s_ref[...] = jnp.where(
+        prev_s = s_ref[0]
+        s_ref[0] = jnp.where(
             owned, scale, jnp.where(unowned, jnp.ones_like(scale), prev_s))
 
 
@@ -503,15 +519,16 @@ def gmm_pallas_quant(a_fp8: jax.Array, s_a: jax.Array, b_fp8: jax.Array,
         plan.check_against(m, block_m, num_groups)
     k_steps = k // block_k
     nq = block_n // QUANT_BLOCK
+    n_steps = n // block_n
 
-    grid = (n // block_n, plan.max_visits, k_steps)
+    grid = (n_steps, plan.max_visits, k_steps)
 
     kernel = functools.partial(
         _gmm_quant_kernel, block_m=block_m, block_n=block_n, block_k=block_k,
         k_steps=k_steps, num_groups=num_groups, out_dtype=out_dtype)
 
     def _run_kernel(group_offsets, group_ids, m_tile_ids):
-        return pl.pallas_call(
+        q, s3 = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
@@ -530,21 +547,25 @@ def gmm_pallas_quant(a_fp8: jax.Array, s_a: jax.Array, b_fp8: jax.Array,
                     # fp8 payload tile — same walk as the plain kernel's out
                     pl.BlockSpec((block_m, block_n),
                                  lambda n_i, t, k_i, go, gi, mi: (mi[t], n_i)),
-                    # 1x128 scales: nq columns per N step, same M-tile walk
-                    pl.BlockSpec((block_m, nq),
-                                 lambda n_i, t, k_i, go, gi, mi: (mi[t], n_i)),
+                    # 1x128 scales: the nq columns of one N step, same
+                    # M-tile walk, in an [N steps, M, nq] slab so the
+                    # block's last dim is the array's own (a (bm, nq)
+                    # block of [M, NB] breaks Mosaic's 128-lane rule)
+                    pl.BlockSpec((1, block_m, nq),
+                                 lambda n_i, t, k_i, go, gi, mi: (n_i, mi[t], 0)),
                 ],
                 scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
             ),
             out_shape=[
                 jax.ShapeDtypeStruct((m, n), q_dtype),
-                jax.ShapeDtypeStruct((m, nb), jnp.float32),
+                jax.ShapeDtypeStruct((n_steps, m, nq), jnp.float32),
             ],
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             ),
             interpret=interpret,
         )(group_offsets, group_ids, m_tile_ids, a_fp8, s_a, b_fp8, s_b)
+        return q, s3.transpose(1, 0, 2).reshape(m, nb)
 
     # all-empty schedule: payload 0 / scale 1 everywhere — bitwise what
     # quantizing the unfused path's all-zero output produces

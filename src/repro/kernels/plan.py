@@ -206,10 +206,7 @@ _DEVICE_DEFAULTS = (
 
 
 def _device_kind() -> str:
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:  # no backend at all — import-time safety
-        return "cpu"
+    return jax.devices()[0].device_kind
 
 
 # ---------------------------------------------------------------------------
@@ -600,7 +597,8 @@ def device_spec(device_kind: Optional[str] = None) -> DeviceSpec:
     for prefix in ("tpu", "cpu"):
         if kind.startswith(prefix):
             return DEVICE_SPECS[prefix]
-    return DEVICE_SPECS["cpu"]
+    raise ValueError(f"no device spec for device kind {kind!r}; "
+                     f"known: {sorted(DEVICE_SPECS)}")
 
 
 # the MXU processes a full 128-row pass regardless of how few rows a tile

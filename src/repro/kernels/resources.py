@@ -78,13 +78,16 @@ FAMILIES = ("gemm", "gemm_quant", "wgrad", "quantize", "act_quant")
 def vmem_budget(device_kind: str) -> int:
     """VMEM budget for a device kind, longest-prefix matched (mirrors
     ``plan.device_spec``'s matching so ``"TPU v5 lite"`` hits the v5e
-    entry)."""
+    entry).  A kind no entry matches raises."""
     kind = device_kind.lower()
     best = None
     for prefix, budget in VMEM_BYTES.items():
         if kind.startswith(prefix) and (best is None or len(prefix) > best[0]):
             best = (len(prefix), budget)
-    return best[1] if best is not None else VMEM_BYTES["cpu"]
+    if best is None:
+        raise ValueError(f"no VMEM budget for device kind {device_kind!r}; "
+                         f"known: {sorted(VMEM_BYTES)}")
+    return best[1]
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,7 @@ concatenated token buffer, and each group's rows contract against the same
 rows of the upstream gradient.  This is the last GEMM of the fp8 training
 step (paper's training workload) and the ROADMAP's "N-side raggedness"
 item — before this kernel the backward detoured through XLA's
-``ragged_dot_general`` fallback (``compat.ragged_wgrad``).
+``ragged_dot_general`` fallback (``dispatch.wgrad_xla_ragged``).
 
 The forward kernel's insight transfers unchanged: the *schedule* depends
 only on ``(group_sizes, M, block_m)``, so the same :class:`TilePlan` built
@@ -47,7 +47,7 @@ Two operand precisions share the schedule machinery:
   * :func:`gmm_pallas_wgrad` — operands arrive un-quantized (bf16/f32):
     DeepSeek-V3 (and the paper) keep wgrad at the highest precision of the
     three training GEMMs, so there is no scale bookkeeping — just f32
-    accumulation of bf16 products, matching ``compat.ragged_wgrad``
+    accumulation of bf16 products, matching ``ragged_dot_general``
     numerics.  This is the default.
   * :func:`gmm_pallas_wgrad_fp8` — the all-fp8 step of arXiv 2505.20524:
     x and dy arrive as fp8 with their 1x128 per-row tile scales (the SAME
@@ -68,7 +68,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels.grouped_gemm_kernel import select_index
 from repro.kernels.plan import (QUANT_BLOCK, KernelConfig, TilePlan,
                                 make_tile_plan)
 
@@ -150,7 +150,7 @@ def _run_ragged_contraction(kernel_body, operands, in_specs, group_sizes, *,
             scratch_shapes=[pltpu.VMEM((wk, wn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((num_groups, k, n), out_dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -300,15 +300,19 @@ def _gmm_wgrad_fp8_kernel(group_offsets_ref, group_ids_ref, m_tile_ids_ref,
 
     # per-row 1x128 tile scales for this visit's K-span / N-span slice
     # (whole scale rows travel on the M-tile like the forward's S_A
-    # over-fetch; the span widens the slice, not the fetch)
+    # over-fetch; the span widens the slice, not the fetch), each column
+    # picked by mask and broadcast over its 128 lanes
+    def expand(s_ref, first, count):                    # -> (bm, count*128)
+        s = s_ref[...]
+        return jnp.concatenate(
+            [jnp.broadcast_to(select_index(s, first + c, 1),
+                              (block_m, QUANT_BLOCK)) for c in range(count)],
+            axis=1)
+
     kq = block_k // QUANT_BLOCK
     nq = block_n // QUANT_BLOCK
-    sx = jax.lax.dynamic_slice(sx_ref[...], (0, k_i * k_span * kq),
-                               (block_m, k_span * kq))
-    sdy = jax.lax.dynamic_slice(sdy_ref[...], (0, n_i * n_span * nq),
-                                (block_m, n_span * nq))
-    sx_full = jnp.repeat(sx, QUANT_BLOCK, axis=1)       # (bm, wk)
-    sdy_full = jnp.repeat(sdy, QUANT_BLOCK, axis=1)     # (bm, wn)
+    sx_full = expand(sx_ref, k_i * k_span * kq, k_span * kq)      # (bm, wk)
+    sdy_full = expand(sdy_ref, n_i * n_span * nq, n_span * nq)    # (bm, wn)
 
     # dequantize-on-visit with the scale-multiply folded into the masked
     # prologue: one jnp.where zeroes unowned rows (whose fp8 payload AND

@@ -25,7 +25,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -214,7 +213,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo_text = compiled.as_text()
     per_type, wire = collective_bytes(hlo_text)
 

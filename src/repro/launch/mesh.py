@@ -7,13 +7,24 @@ init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding code
+    (``distributed.context.constrain``, ``named_shardings``, the
+    ``shard_map`` MoE path) places arrays with ``NamedSharding`` and leaves
+    propagation to GSPMD, which ``Explicit`` axes — ``jax.make_mesh``'s
+    default since JAX 0.7 — refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for(n_devices: int, *, model_parallel: int | None = None):
@@ -26,7 +37,7 @@ def make_mesh_for(n_devices: int, *, model_parallel: int | None = None):
                 model_parallel = cand
                 break
     data = n_devices // model_parallel
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+    return make_mesh((data, model_parallel), ("data", "model"))
 
 
 def local_mesh():

@@ -11,6 +11,7 @@ import time
 import jax
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import make_model, synthetic_batch
 from repro.serve.engine import Engine
 
@@ -25,6 +26,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = make_model(cfg)

@@ -31,6 +31,7 @@ from repro.configs import get_config, smoke_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed import context as dctx
 from repro.distributed.sharding import named_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import local_mesh
 from repro.models.model_zoo import make_model
 from repro.optim import adamw
@@ -56,6 +57,7 @@ def main(argv=None):
                     help="inject a crash (restart testing)")
     ap.add_argument("--dtype", default=None, choices=[None, "f32", "bf16"])
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     repl = {}

@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.base import ModelConfig
 from repro.core.moe import (MoEConfig, init_moe_params, moe_apply,
                             ep_size_for, shard_moe_params)
@@ -101,8 +99,8 @@ def _apply_moe(p, x, cfg: ModelConfig):
                            ep_rank=rank, ep_size=ep, axis_name="model")
         return y.reshape(bl, sl, dl), aux["load_balance_loss"]
 
-    y, lb = shard_map(local_fn, mesh=mesh, in_specs=(pspecs, xspec),
-                      out_specs=(xspec, P()), check_vma=False)(p, x)
+    y, lb = jax.shard_map(local_fn, mesh=mesh, in_specs=(pspecs, xspec),
+                          out_specs=(xspec, P()), check_vma=False)(p, x)
     return y, lb
 
 

@@ -44,8 +44,10 @@ def init_opt_state(params, cfg: OptConfig):
              "v": jax.tree.map(jnp.zeros_like, zeros),
              "step": jnp.zeros((), jnp.int32)}
     if cfg.use_master:
+        # a copy even where a param is already f32: the step donates
+        # params and state, and one buffer cannot be donated twice
         state["master"] = jax.tree.map(
-            lambda p: p.astype(jnp.float32), params)
+            lambda p: jnp.array(p, jnp.float32, copy=True), params)
     if cfg.compress_grads:
         state["ef"] = jax.tree.map(jnp.zeros_like, zeros)
     return state

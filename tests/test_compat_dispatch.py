@@ -1,61 +1,45 @@
 """Compat layer + grouped-GEMM dispatch registry.
 
-Covers the ISSUE-1 acceptance surface:
-  * capability probes are monkeypatchable and drive backend selection —
-    each backend is selected (auto) or refused (explicit request) per the
-    probed environment, with a reasoned error instead of AttributeError;
-  * the two wgrad formulations (``ragged_dot_general`` vs the
-    transpose-of-``ragged_dot`` fallback) agree numerically with each
-    other and with a dense one-hot oracle;
+Covers:
+  * the TPU probe is monkeypatchable and drives backend selection — each
+    backend is selected (auto) or refused (explicit request) per the
+    probed platform, with a reasoned error instead of AttributeError;
+  * the ``ragged_dot_general`` wgrad fallback agrees with a dense one-hot
+    oracle;
   * every CPU-runnable backend produces matching outputs on the
     equivalence fixtures, including a dispatch-level re-run of the paper's
     bitwise padded-baseline equivalence claim.
 """
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import compat
 from repro.kernels import dispatch, ref
+from repro.kernels.dispatch import OpKey
 
 
 # ---------------------------------------------------------------------------
-# compat probes + shard_map
+# platform probe
 # ---------------------------------------------------------------------------
 
 def test_probes_return_bool():
-    for probe in (compat.has_tpu, compat.has_ragged_dot,
-                  compat.has_ragged_dot_general, compat.has_shard_map_in_jax):
-        assert isinstance(probe(), bool)
+    assert isinstance(compat.has_tpu(), bool)
 
 
 def test_tpu_compiler_params_constructs():
-    p = compat.tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert isinstance(p, compat.TPUCompilerParams)
-
-
-def test_shard_map_check_vma_translated():
-    """compat.shard_map accepts the modern ``check_vma=`` kwarg on every
-    JAX (0.4.x spells it ``check_rep``)."""
-    mesh = jax.make_mesh((1,), ("x",))
-    from jax.sharding import PartitionSpec as P
-    fn = compat.shard_map(lambda a: a * 2, mesh=mesh, in_specs=P("x"),
-                          out_specs=P("x"), check_vma=False)
-    out = fn(jnp.arange(4.0))
-    np.testing.assert_array_equal(np.asarray(out), [0.0, 2.0, 4.0, 6.0])
-
-
-def test_cost_analysis_normalized_to_dict():
-    compiled = jax.jit(lambda x: x @ x).lower(
-        jax.ShapeDtypeStruct((8, 8), jnp.float32)).compile()
-    cost = compat.cost_analysis(compiled)
-    assert isinstance(cost, dict)
+    """The kernels build the installed JAX's ``pltpu.CompilerParams``
+    directly; the spelling they use must construct."""
+    p = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+    assert tuple(p.dimension_semantics) == ("parallel", "arbitrary")
 
 
 # ---------------------------------------------------------------------------
-# wgrad formulations
+# wgrad fallback
 # ---------------------------------------------------------------------------
 
 def _wgrad_oracle(x, dy, sizes):
@@ -76,50 +60,8 @@ def test_ragged_wgrad_matches_dense_oracle(sizes):
     x = jnp.asarray(rng.standard_normal((m, 16)), jnp.float32)
     dy = jnp.asarray(rng.standard_normal((m, 8)), jnp.float32)
     gs = jnp.asarray(sizes, jnp.int32)
-    dw = compat.ragged_wgrad(x, dy, gs, num_groups=len(sizes))
+    dw = dispatch.wgrad_xla_ragged(x, dy, gs, num_groups=len(sizes))
     np.testing.assert_allclose(np.asarray(dw), _wgrad_oracle(x, dy, sizes),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_wgrad_formulations_agree():
-    """Pin numerical agreement between the ragged_dot_general spelling and
-    the transpose-of-ragged_dot fallback.  When this JAX lacks
-    ``ragged_dot_general`` the fallback is compared against the dense
-    oracle (bitwise-level f32 tolerance) so the pin still bites."""
-    sizes = (33, 1, 0, 62)
-    rng = np.random.default_rng(0)
-    m = sum(sizes)
-    x = jnp.asarray(rng.standard_normal((m, 32)), jnp.bfloat16)
-    dy = jnp.asarray(rng.standard_normal((m, 24)), jnp.bfloat16)
-    gs = jnp.asarray(sizes, jnp.int32)
-    via_transpose = compat._ragged_wgrad_via_transpose(
-        x, dy, gs, num_groups=len(sizes))
-    if compat.has_ragged_dot_general():
-        dn = jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=[0],
-            rhs_group_dimensions=[])
-        direct = jax.lax.ragged_dot_general(
-            x, dy, gs, dn, preferred_element_type=jnp.float32)
-    else:
-        direct = jnp.asarray(_wgrad_oracle(x.astype(jnp.float32),
-                                           dy.astype(jnp.float32), sizes))
-    np.testing.assert_allclose(np.asarray(via_transpose),
-                               np.asarray(direct), rtol=1e-5, atol=1e-5)
-
-
-def test_ragged_dot_dense_fallback_matches_primitive(monkeypatch):
-    sizes = (3, 9, 4)
-    rng = np.random.default_rng(2)
-    m = sum(sizes)
-    x = jnp.asarray(rng.standard_normal((m, 16)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((len(sizes), 16, 8)), jnp.float32)
-    gs = jnp.asarray(sizes, jnp.int32)
-    real = compat.ragged_dot(x, w, gs, preferred_element_type=jnp.float32)
-    monkeypatch.setattr(compat, "has_ragged_dot", lambda: False)
-    fallback = compat.ragged_dot(x, w, gs,
-                                 preferred_element_type=jnp.float32)
-    np.testing.assert_allclose(np.asarray(fallback), np.asarray(real),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -141,13 +83,14 @@ def test_auto_prefers_pallas_on_tpu(monkeypatch):
 
 def test_auto_prefers_xla_ragged_on_cpu(monkeypatch):
     monkeypatch.setattr(compat, "has_tpu", lambda: False)
-    monkeypatch.setattr(compat, "has_ragged_dot", lambda: True)
     assert dispatch.resolve_backend("auto") == "xla_ragged"
 
 
 def test_auto_falls_back_to_interpret(monkeypatch):
     monkeypatch.setattr(compat, "has_tpu", lambda: False)
-    monkeypatch.setattr(compat, "has_ragged_dot", lambda: False)
+    table = dispatch._OPERATORS[OpKey("gemm", "fp8")]
+    monkeypatch.setitem(table, "xla_ragged", dataclasses.replace(
+        table["xla_ragged"], available=lambda: (False, "refused here")))
     assert dispatch.resolve_backend("auto") == "pallas_interpret"
 
 
@@ -164,11 +107,25 @@ def test_pallas_refused_without_tpu(monkeypatch):
     assert ei.value.backend == "pallas"
 
 
-def test_xla_ragged_refused_without_ragged_dot(monkeypatch):
-    monkeypatch.setattr(compat, "has_ragged_dot", lambda: False)
-    for name in ("xla_ragged", "xla_exact"):
-        with pytest.raises(dispatch.BackendUnavailableError):
-            dispatch.resolve_backend(name)
+def test_interpret_refused_on_tpu(monkeypatch):
+    monkeypatch.setattr(compat, "has_tpu", lambda: True)
+    with pytest.raises(dispatch.BackendUnavailableError, match="interpret"):
+        dispatch.resolve_backend("pallas_interpret")
+
+
+def test_tile_fallback_warns_once_per_shape_on_tpu(monkeypatch):
+    from repro.kernels.plan import KernelConfig
+    monkeypatch.setattr(compat, "has_tpu", lambda: True)
+    monkeypatch.setattr(dispatch, "_FALLBACK_WARNED", set())
+    tile = (KernelConfig(block_k=256), 128, 128, 128)    # K=128 % 256 != 0
+    with pytest.warns(UserWarning, match="xla_ragged"):
+        assert dispatch.resolve(("wgrad", "bf16"), "auto",
+                                tile=tile) == "xla_ragged"
+    import warnings
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert dispatch.resolve(("wgrad", "bf16"), "auto",
+                                tile=tile) == "xla_ragged"
 
 
 def test_unknown_backend_raises_valueerror():

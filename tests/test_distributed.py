@@ -32,7 +32,8 @@ def test_moe_ep_matches_single_device():
         from jax.sharding import PartitionSpec as P
         from repro.core.moe import (MoEConfig, init_moe_params, moe_apply,
                                     shard_moe_params)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = MoEConfig(num_experts=8, top_k=2, d_model=128, d_ff_expert=64,
                         num_shared_experts=1, capacity_factor=8.0)
         params = init_moe_params(jax.random.PRNGKey(0), cfg)
@@ -48,10 +49,9 @@ def test_moe_ep_matches_single_device():
             y, _ = moe_apply(p, xl.reshape(b*s, d), cfg, ep_rank=rank,
                              ep_size=ep, axis_name="model")
             return y.reshape(b, s, d)
-        from repro.compat import shard_map
-        fn = jax.jit(shard_map(local_fn, mesh=mesh,
-                               in_specs=(pspecs, xspec),
-                               out_specs=xspec, check_vma=False))
+        fn = jax.jit(jax.shard_map(local_fn, mesh=mesh,
+                                   in_specs=(pspecs, xspec),
+                                   out_specs=xspec, check_vma=False))
         y = fn(params, x)
         err = float(jnp.max(jnp.abs(y - y_ref)))
         assert err < 1e-3, err
@@ -66,7 +66,8 @@ def test_moe_tp_fallback_matches_single_device():
         from jax.sharding import PartitionSpec as P
         from repro.core.moe import (MoEConfig, init_moe_params, moe_apply,
                                     shard_moe_params)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         # 6 experts % 4 != 0 -> TP-on-d_ff fallback (qwen2-moe regime)
         cfg = MoEConfig(num_experts=6, top_k=2, d_model=128, d_ff_expert=64,
                         num_shared_experts=1)
@@ -80,10 +81,9 @@ def test_moe_tp_fallback_matches_single_device():
             y, _ = moe_apply(p, xl.reshape(b*s, d), cfg, ep_rank=0,
                              ep_size=1, axis_name="model")
             return y.reshape(b, s, d)
-        from repro.compat import shard_map
-        fn = jax.jit(shard_map(local_fn, mesh=mesh,
-                               in_specs=(pspecs, xspec),
-                               out_specs=xspec, check_vma=False))
+        fn = jax.jit(jax.shard_map(local_fn, mesh=mesh,
+                                   in_specs=(pspecs, xspec),
+                                   out_specs=xspec, check_vma=False))
         y = fn(params, x)
         err = float(jnp.max(jnp.abs(y.reshape(-1, 128) - y_ref)))
         assert err < 1e-3, err
@@ -114,7 +114,8 @@ def test_sharded_train_step_matches_unsharded():
         # unsharded reference
         _, _, m_ref = jax.jit(step)(params, opt, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         dctx.set_mesh(mesh)
         pshard = named_shardings(params, mesh, moe_mode="ep")
         params_s = jax.device_put(params, pshard)
@@ -137,11 +138,9 @@ def test_dryrun_lowers_on_small_mesh():
         import repro.launch.mesh as mesh_mod
         # shrink the production mesh for the test
         mesh_mod.make_production_mesh = \\
-            lambda multi_pod=False: jax.make_mesh((2, 2, 2) if multi_pod
-                                                  else (4, 2),
-                                                  ("pod", "data", "model")
-                                                  if multi_pod else
-                                                  ("data", "model"))
+            lambda multi_pod=False: mesh_mod.make_mesh(
+                (2, 2, 2) if multi_pod else (4, 2),
+                ("pod", "data", "model") if multi_pod else ("data", "model"))
         d.make_production_mesh = mesh_mod.make_production_mesh
         import repro.configs as C
         real_get = C.get_config

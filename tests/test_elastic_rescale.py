@@ -31,7 +31,7 @@ def test_checkpoint_reshards_onto_smaller_mesh(tmp_path):
         from repro.configs import smoke_config
         from repro.distributed import context as dctx
         from repro.distributed.sharding import named_shardings
-        from repro.launch.mesh import make_mesh_for
+        from repro.launch.mesh import make_mesh, make_mesh_for
         from repro.models.model_zoo import make_model, synthetic_batch
 
         cfg = dataclasses.replace(smoke_config("qwen3-1.7b"),
@@ -45,8 +45,8 @@ def test_checkpoint_reshards_onto_smaller_mesh(tmp_path):
         ckpt.save({str(tmp_path)!r}, 7, {{"params": p8}})
 
         # "after failure": 4 surviving devices, (2 data, 2 model)
-        mesh4 = jax.make_mesh((2, 2), ("data", "model"),
-                              devices=jax.devices()[:4])
+        mesh4 = make_mesh((2, 2), ("data", "model"),
+                          devices=jax.devices()[:4])
         like = jax.tree.map(
             lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                               sharding=s),

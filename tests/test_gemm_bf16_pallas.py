@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.kernels import dispatch
 from repro.kernels import plan as plan_mod
 from repro.kernels.dispatch import gmm_bf16_xla_exact
@@ -59,9 +58,9 @@ def test_close_to_ragged_dot_baseline(sizes, m, k, n, bm):
     x, w, gs = _inputs(sizes, m, k, n)
     out = gmm_pallas_bf16(x, w, gs, num_groups=len(sizes), block_m=bm,
                           interpret=True).astype(jnp.float32)
-    rd = compat.ragged_dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-                           gs, preferred_element_type=jnp.float32
-                           ).astype(jnp.bfloat16).astype(jnp.float32)
+    rd = jax.lax.ragged_dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                            gs, preferred_element_type=jnp.float32
+                            ).astype(jnp.bfloat16).astype(jnp.float32)
     total = int(sum(sizes))
     np.testing.assert_allclose(np.asarray(out[:total]),
                                np.asarray(rd[:total]),

@@ -107,7 +107,7 @@ def test_fp8_tail_dx_rows_exactly_zero(backend):
 
 def test_fp8_bwd_wgrad_runs_through_registry(monkeypatch):
     """The fp8 backward's dw goes through dispatch.grouped_gemm_wgrad —
-    compat.ragged_wgrad is only the registry's fallback entry now."""
+    ragged_dot_general is only the registry's fallback entry now."""
     x, w, gs = _setup()
     calls = []
     real = dispatch.grouped_gemm_wgrad

@@ -17,6 +17,8 @@ Covers the ISSUE-5 acceptance surface:
     two calls with the same static shape build exactly one plan
     (regression for the historical per-call re-planning).
 """
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -160,14 +162,14 @@ def test_wgrad_alias_outputs_bitwise_both_precisions(fixtures, backend):
 
 def test_bf16_gemm_family_matches_ragged_dot(fixtures):
     """The bf16 baseline is now a registry citizen; its output must be
-    bitwise what the pre-refactor direct compat.ragged_dot produced."""
+    bitwise what a direct jax.lax.ragged_dot produces."""
     f = fixtures
     x16 = f["a"].astype(jnp.bfloat16)
     w16 = f["b"].astype(jnp.bfloat16)
     got = dispatch.grouped_gemm_bf16(x16, w16, f["gs"],
                                      out_dtype=jnp.float32)
-    want = compat.ragged_dot(x16, w16, f["gs"],
-                             preferred_element_type=jnp.float32)
+    want = jax.lax.ragged_dot(x16, w16, f["gs"],
+                              preferred_element_type=jnp.float32)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -185,7 +187,9 @@ def test_quantize_family_entries_and_explicit_semantics(monkeypatch):
     monkeypatch.setattr(compat, "has_tpu", lambda: False)
     with pytest.raises(dispatch.BackendUnavailableError):
         dispatch.quantize_tilewise(x, backend="pallas")
-    monkeypatch.setattr(compat, "has_ragged_dot", lambda: False)
+    table = dispatch._OPERATORS[OpKey("quantize", "fp8")]
+    monkeypatch.setitem(table, "xla_ragged", dataclasses.replace(
+        table["xla_ragged"], available=lambda: (False, "refused here")))
     with pytest.raises(dispatch.BackendUnavailableError):
         dispatch.quantize_tilewise(x, backend="xla_ragged")
 

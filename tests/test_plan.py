@@ -8,9 +8,10 @@ The two load-bearing pins:
     forward+backward builds group metadata ONCE (counting monkeypatch),
     i.e. the plan is genuinely shared across gate/up/down + dgrads;
   * ``test_moe_fp8_bitwise_golden`` — outputs/grads on
-    ``pallas_interpret`` are bitwise-identical to the pre-refactor
-    implementation (golden values captured at the parent commit).
+    ``pallas_interpret`` agree with the same layer on the ``xla_exact``
+    oracles.
 """
+import dataclasses
 import os
 
 import numpy as np
@@ -225,38 +226,40 @@ def test_moe_fwd_bwd_builds_metadata_exactly_once(monkeypatch):
     assert [c[3] for c in calls] == [cfg.num_experts, 1]
 
 
-# Golden values pin the fp8 MoE fwd+bwd bitwise so refactors stay pure
-# plumbing.  Recaptured once for the init_moe_params key-split bugfix
-# (splitting 7 keys instead of 6 redraws every param — the distributions
-# are unchanged, the draws are not), and again for the shared-expert
-# precision bugfix (the shared FFN now runs fp8 under precision="fp8"
-# instead of silently staying bf16 — only shared_* grad norms and the
-# forward sums moved).  The fused silu·mul→quantize epilogue landing in
-# the same PR was verified bitwise-neutral: router/w_gate/w_up/w_down
-# grad norms are unchanged from the previous goldens.
-_GOLDEN_FWD_SUM = 12.953460693359375
-_GOLDEN_LOSS = -12.785236358642578
-_GOLDEN_Y00 = -0.022556953132152557
-_GOLDEN_GRADNORMS = {
-    "router": 178.5314483642578,
-    "shared_down": 415.6036376953125,
-    "shared_gate": 450.72210693359375,
-    "shared_up": 436.6423034667969,
-    "w_down": 271.82525634765625,
-    "w_gate": 289.45892333984375,
-    "w_up": 267.9383544921875,
-}
-
-
 @pytest.mark.slow
 def test_moe_fp8_bitwise_golden():
+    """The fp8 MoE fwd+bwd on the kernel path agrees with the same layer
+    on the ``xla_exact`` oracles (per-128-K-block f32 GEMMs, dense f32
+    wgrad, reference quantizers).  The oracle shares the routing and the
+    quantization recipe, so what is left is f32 reassociation: the
+    forward and loss agree to ~1e-6 of their scale, the grads to ~1e-4."""
     cfg, params, x = _moe_fixture()
     (l, y), g = jax.value_and_grad(_moe_loss(cfg), has_aux=True)(params, x)
-    assert float(jnp.sum(y.astype(jnp.float32))) == _GOLDEN_FWD_SUM
-    assert float(l) == _GOLDEN_LOSS
-    assert float(y[0, 0]) == _GOLDEN_Y00
-    for name, want in _GOLDEN_GRADNORMS.items():
-        assert float(jnp.linalg.norm(g[name])) == want, name
+    oracle = dataclasses.replace(cfg, backend="xla_exact")
+    (l_o, y_o), g_o = jax.value_and_grad(_moe_loss(oracle),
+                                         has_aux=True)(params, x)
+
+    def rel(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        return float(np.abs(a - b).max() / np.abs(b).max())
+
+    assert rel(y, y_o) < 1e-5
+    assert abs(float(l) - float(l_o)) < 1e-5 * abs(float(l_o))
+    assert set(g) == set(g_o)
+    for name in g:
+        assert rel(g[name], g_o[name]) < 1e-3, name
+
+
+@pytest.mark.parametrize("kind,want", [("TPU v5 lite", "tpu v5e"),
+                                       ("TPU v4", "tpu"), ("cpu", "cpu")])
+def test_device_spec_matches_known_kinds(kind, want):
+    assert plan_mod.device_spec(kind) is plan_mod.DEVICE_SPECS[want]
+
+
+def test_device_spec_raises_on_unknown_kind():
+    """An unknown accelerator gets no borrowed CPU numbers."""
+    with pytest.raises(ValueError, match="unknown accelerator"):
+        plan_mod.device_spec("unknown accelerator")
 
 
 def test_capacity_respects_block_m_alignment():

@@ -86,7 +86,8 @@ def test_vmem_budget_prefix_matching():
     assert res.vmem_budget("tpu v5e") == 16 * 2**20
     assert res.vmem_budget("tpu v4") == 32 * 2**20
     assert res.vmem_budget("cpu") == 16 * 2**20
-    assert res.vmem_budget("unknown accelerator") == 16 * 2**20
+    with pytest.raises(ValueError, match="unknown accelerator"):
+        res.vmem_budget("unknown accelerator")
 
 
 def test_infeasible_reason_cases():
