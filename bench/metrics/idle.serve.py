@@ -1,0 +1,8 @@
+"""Share of the traced window in which no op ran on the device, in a
+serving cell."""
+
+
+def read(ctx):
+    if ctx.kind != "serve" or ctx.trace is None:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
