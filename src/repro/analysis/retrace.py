@@ -50,7 +50,7 @@ COMPILE_CONTRACT_MODULES = ("repro.core.grouped_gemm", "repro.core.moe",
 
 def _fn_name(fun) -> str:
     # functools.partial objects have no __name__; fall back to the
-    # wrapped callable's (Engine jits partial(self._prefill_impl))
+    # wrapped callable's (a jitted partial compiles as ``jit__unknown``)
     return (getattr(fun, "__name__", None)
             or getattr(getattr(fun, "func", None), "__name__", None)
             or "<anonymous>")

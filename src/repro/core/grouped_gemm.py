@@ -45,6 +45,24 @@ from repro.kernels import ref as kref
 from repro.kernels.plan import KernelConfig, TilePlan, make_tile_plan, \
     resolve_config
 from repro.core import quantization as q
+from repro.scopes import QUANT_ACT, QUANT_WEIGHTS, scope
+
+
+def _quant_act(x, config):
+    """Standalone 1x128 quantization of an activation, f32 cast included."""
+    with scope(QUANT_ACT):
+        return q.quantize_tilewise(x.astype(jnp.float32),
+                                   backend=config.backend, config=config)
+
+
+def _quant_weights(w, config, *, transpose=False):
+    """128x128-block quantization of ``w`` [G, K, N] (of each group's
+    transpose with ``transpose``), f32 upcast included."""
+    with scope(QUANT_WEIGHTS):
+        if transpose:
+            w = jnp.swapaxes(w, 1, 2)
+        return q.quantize_blockwise_batched(w.astype(jnp.float32),
+                                            backend=config.backend)
 
 
 # ---------------------------------------------------------------------------
@@ -84,12 +102,10 @@ def _fp8_fwd(x, w, group_sizes, plan, qa, config):
     # quantize-once: a caller-supplied QuantizedActivation (the MoE layer
     # shares one across the gate/up GEMMs) replaces the tilewise quant of x
     if qa is None:
-        a8, sa = q.quantize_tilewise(x.astype(jnp.float32),
-                                     backend=config.backend, config=config)
+        a8, sa = _quant_act(x, config)
     else:
         a8, sa = qa.q, qa.scale
-    b8, sb = q.quantize_blockwise_batched(w.astype(jnp.float32),
-                                          backend=config.backend)
+    b8, sb = _quant_weights(w, config)
     # plan-once/run-many: one TilePlan per group_sizes serves this forward
     # GEMM *and* the backward dgrad (the schedule depends only on M-side
     # raggedness, not on which weight it multiplies)
@@ -117,11 +133,8 @@ def _fp8_bwd(config, res, dy):
     # dgrad: dx = dy @ w^T  (fp8 through the padding-free kernel, reusing
     # the forward's TilePlan — same group_sizes, same schedule).  This one
     # quantize_tilewise(dy) also feeds the fp8 wgrad below.
-    d8, sd = q.quantize_tilewise(dy.astype(jnp.float32),
-                                 backend=config.backend, config=config)
-    wt = jnp.swapaxes(w, 1, 2)                       # [G, N, K]
-    bt8, sbt = q.quantize_blockwise_batched(wt.astype(jnp.float32),
-                                            backend=config.backend)
+    d8, sd = _quant_act(dy, config)
+    bt8, sbt = _quant_weights(w, config, transpose=True)   # [G, N, K]
     dx = dispatch.grouped_gemm_fp8(d8, sd, bt8, sbt, group_sizes,
                                    config=config.with_(out_dtype=jnp.float32),
                                    plan=plan)
@@ -206,8 +219,7 @@ def _fused_fwd(g, u, w, group_sizes, plan, ctx):
     # QuantizedActivation straight from the epilogue kernel
     qh = q.fused_act_quantize(g, u, act=act, backend=config.backend,
                               config=config)
-    b8, sb = q.quantize_blockwise_batched(w.astype(jnp.float32),
-                                          backend=config.backend)
+    b8, sb = _quant_weights(w, config)
     if plan is None and dispatch.backend_uses_plan(config.backend):
         plan = make_tile_plan(group_sizes, g.shape[0],
                               block_m=config.block_m,
@@ -226,11 +238,8 @@ def _fused_bwd(ctx, res, dy):
     g, u, h_res, w, group_sizes, plan = res
     num_groups = w.shape[0]
     # one quantize_tilewise(dy) serves the dgrad AND the fp8 wgrad
-    d8, sd = q.quantize_tilewise(dy.astype(jnp.float32),
-                                 backend=config.backend, config=config)
-    wt = jnp.swapaxes(w, 1, 2)                       # [G, N, K]
-    bt8, sbt = q.quantize_blockwise_batched(wt.astype(jnp.float32),
-                                            backend=config.backend)
+    d8, sd = _quant_act(dy, config)
+    bt8, sbt = _quant_weights(w, config, transpose=True)   # [G, N, K]
     dh = dispatch.grouped_gemm_fp8(d8, sd, bt8, sbt, group_sizes,
                                    config=config.with_(out_dtype=jnp.float32),
                                    plan=plan)
@@ -276,8 +285,7 @@ def _ffn_fwd(x, w_gate, w_up, w_down, group_sizes, plan, qa, ctx):
     # quantize-once: ONE tilewise quant of x feeds the gate AND up GEMMs
     # (and, under wgrad_precision="fp8", both of their wgrads)
     if qa is None:
-        a8, sa = q.quantize_tilewise(x.astype(jnp.float32),
-                                     backend=config.backend, config=config)
+        a8, sa = _quant_act(x, config)
     else:
         a8, sa = qa.q, qa.scale
     num_groups = w_up.shape[0]
@@ -291,15 +299,13 @@ def _ffn_fwd(x, w_gate, w_up, w_down, group_sizes, plan, qa, ctx):
     # dtype, chosen to match what the unfused composition would have
     # stored (x.dtype), so fused-vs-unfused stays bitwise at this seam.
     idt = x.dtype
-    bu8, sbu = q.quantize_blockwise_batched(w_up.astype(jnp.float32),
-                                            backend=config.backend)
+    bu8, sbu = _quant_weights(w_up, config)
     u8, su = dispatch.grouped_gemm_quant(a8, sa, bu8, sbu, group_sizes,
                                          num_groups=num_groups,
                                          config=config, out_dtype=idt,
                                          plan=plan)
     if w_gate is not None:
-        bg8, sbg = q.quantize_blockwise_batched(w_gate.astype(jnp.float32),
-                                                backend=config.backend)
+        bg8, sbg = _quant_weights(w_gate, config)
         g8, sg = dispatch.grouped_gemm_quant(a8, sa, bg8, sbg, group_sizes,
                                              num_groups=num_groups,
                                              config=config, out_dtype=idt,
@@ -311,8 +317,7 @@ def _ffn_fwd(x, w_gate, w_up, w_down, group_sizes, plan, qa, ctx):
         g8 = sg = None
         qh = q.fused_act_quantize_fp8(u8, su, act=act,
                                       backend=config.backend, config=config)
-    bd8, sbd = q.quantize_blockwise_batched(w_down.astype(jnp.float32),
-                                            backend=config.backend)
+    bd8, sbd = _quant_weights(w_down, config)
     y = dispatch.grouped_gemm_fp8(qh.q, qh.scale, bd8, sbd, group_sizes,
                                   config=config, plan=plan)
     if config.wgrad_precision == "fp8":
@@ -335,11 +340,8 @@ def _ffn_bwd(ctx, res, dy):
     num_groups = w_up.shape[0]
     f32cfg = config.with_(out_dtype=jnp.float32)
     # ONE quantize_tilewise(dy) serves the down dgrad AND its fp8 wgrad
-    d8, sd = q.quantize_tilewise(dy.astype(jnp.float32),
-                                 backend=config.backend, config=config)
-    wdt8, sdt = q.quantize_blockwise_batched(
-        jnp.swapaxes(w_down, 1, 2).astype(jnp.float32),
-        backend=config.backend)
+    d8, sd = _quant_act(dy, config)
+    wdt8, sdt = _quant_weights(w_down, config, transpose=True)
     dh = dispatch.grouped_gemm_fp8(d8, sd, wdt8, sdt, group_sizes,
                                    config=f32cfg, plan=plan)
     # recompute the activation from the fp8 producer residuals — the
@@ -358,17 +360,13 @@ def _ffn_bwd(ctx, res, dy):
     # quantize dg/du ONCE each: the records serve the gate/up dgrads and,
     # under wgrad_precision="fp8", the matching wgrads.  Total standalone
     # quantize_tilewise calls for fwd+bwd: x, dy, dg, du — never h.
-    du8, sdu = q.quantize_tilewise(du, backend=config.backend, config=config)
-    wut8, sut = q.quantize_blockwise_batched(
-        jnp.swapaxes(w_up, 1, 2).astype(jnp.float32), backend=config.backend)
+    du8, sdu = _quant_act(du, config)
+    wut8, sut = _quant_weights(w_up, config, transpose=True)
     dx = dispatch.grouped_gemm_fp8(du8, sdu, wut8, sut, group_sizes,
                                    config=f32cfg, plan=plan)
     if w_gate is not None:
-        dg8, sdg = q.quantize_tilewise(dg, backend=config.backend,
-                                       config=config)
-        wgt8, sgt = q.quantize_blockwise_batched(
-            jnp.swapaxes(w_gate, 1, 2).astype(jnp.float32),
-            backend=config.backend)
+        dg8, sdg = _quant_act(dg, config)
+        wgt8, sgt = _quant_weights(w_gate, config, transpose=True)
         dx = dx + dispatch.grouped_gemm_fp8(dg8, sdg, wgt8, sgt, group_sizes,
                                             config=f32cfg, plan=plan)
     if config.wgrad_precision == "fp8":

@@ -35,6 +35,8 @@ from repro.core.grouped_gemm import (dense_ffn_fp8, dense_linear_fp8,
 from repro.core.quantization import quantize_activation
 from repro.kernels import dispatch
 from repro.kernels.plan import KernelConfig, make_tile_plan, resolve_config
+from repro.scopes import (MOE_COMBINE, MOE_EXPERTS, MOE_PACK, MOE_ROUTE,
+                          MOE_SHARED, QUANT_ACT, scope)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,42 +140,45 @@ def moe_apply(params, x, cfg: MoEConfig, *, ep_rank=0, ep_size: int = 1,
     kcfg = resolve_config(cfg.kernel_config, backend=cfg.backend)
 
     # ---- routing (replicated) ------------------------------------------
-    logits = x.astype(cfg.router_dtype) @ params["router"].astype(
-        cfg.router_dtype)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, ids = jax.lax.top_k(probs, k)                  # [T, k]
-    if cfg.norm_topk_prob:
-        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    with scope(MOE_ROUTE):
+        logits = x.astype(cfg.router_dtype) @ params["router"].astype(
+            cfg.router_dtype)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, ids = jax.lax.top_k(probs, k)              # [T, k]
+        if cfg.norm_topk_prob:
+            weights = weights / jnp.sum(weights, -1, keepdims=True)
 
     # ---- pack rows routed to local experts into the capacity buffer ----
     num_slots = t * k
     cap = _capacity(num_slots, ep_size, cfg.capacity_factor,
                     align=kcfg.block_m)
-    flat_ids = ids.reshape(-1)                              # [T*k]
-    local_id = flat_ids - lo
-    is_local = (local_id >= 0) & (local_id < e_loc)
-    sort_key = jnp.where(is_local, local_id, e_loc)         # dead rows last
-    order = jnp.argsort(sort_key)                           # stable
-    if cap > num_slots:
-        # tile-aligned capacity can exceed the slot count by < block_m;
-        # replicate the last slot into the padding rows.  The replica may
-        # duplicate a REAL token's row — that is safe only because those
-        # rows sit beyond sum(group_sizes): every kernel path zero-fills
-        # them forward and backward, and the combine's `valid` mask below
-        # excludes them — do not weaken either of those invariants
-        order = jnp.pad(order, (0, cap - num_slots), mode="edge")
-    sel = order[:cap]                                       # packed slots
+    with scope(MOE_PACK):
+        flat_ids = ids.reshape(-1)                          # [T*k]
+        local_id = flat_ids - lo
+        is_local = (local_id >= 0) & (local_id < e_loc)
+        sort_key = jnp.where(is_local, local_id, e_loc)     # dead rows last
+        order = jnp.argsort(sort_key)                       # stable
+        if cap > num_slots:
+            # tile-aligned capacity can exceed the slot count by <
+            # block_m; replicate the last slot into the padding rows.  The
+            # replica may duplicate a REAL token's row — that is safe only
+            # because those rows sit beyond sum(group_sizes): every kernel
+            # path zero-fills them forward and backward, and the combine's
+            # `valid` mask below excludes them — do not weaken either of
+            # those invariants
+            order = jnp.pad(order, (0, cap - num_slots), mode="edge")
+        sel = order[:cap]                                   # packed slots
 
-    gs_full = jnp.bincount(jnp.where(is_local, local_id, e_loc),
-                           length=e_loc + 1)[:e_loc]
-    # clip group sizes to the capacity prefix (drops bias to high ids)
-    starts = jnp.concatenate([jnp.zeros(1, gs_full.dtype),
-                              jnp.cumsum(gs_full)[:-1]])
-    gs = jnp.clip(jnp.minimum(gs_full, cap - starts), 0)
-    total = jnp.sum(gs)
+        gs_full = jnp.bincount(jnp.where(is_local, local_id, e_loc),
+                               length=e_loc + 1)[:e_loc]
+        # clip group sizes to the capacity prefix (drops bias to high ids)
+        starts = jnp.concatenate([jnp.zeros(1, gs_full.dtype),
+                                  jnp.cumsum(gs_full)[:-1]])
+        gs = jnp.clip(jnp.minimum(gs_full, cap - starts), 0)
+        total = jnp.sum(gs)
 
-    token_of = sel // k
-    xs = jnp.take(x, token_of, axis=0)                      # [cap, d]
+        token_of = sel // k
+        xs = jnp.take(x, token_of, axis=0)                  # [cap, d]
 
     if cfg.dispatch == "dense":
         # GShard-style capacity buckets: [E_loc, cap_e, d] batched einsum.
@@ -182,23 +187,24 @@ def moe_apply(params, x, cfg: MoEConfig, *, ep_rank=0, ep_size: int = 1,
         # silently drop tokens the ragged path keeps
         cap_e = max(-(-int(num_slots * cfg.capacity_factor) // e), 1)
         cap_e = (cap_e + 7) // 8 * 8
-        ends = jnp.cumsum(gs)
-        row = jnp.arange(cap)
-        gid = jnp.searchsorted(ends, row, side="right")
-        gid = jnp.minimum(gid, e_loc - 1)
-        pos = row - jnp.concatenate([jnp.zeros(1, ends.dtype),
-                                     ends[:-1]])[gid]
-        keep = (row < jnp.sum(gs)) & (pos < cap_e)
-        xe = jnp.zeros((e_loc, cap_e, d), x.dtype).at[
-            jnp.where(keep, gid, e_loc - 1),
-            jnp.where(keep, pos, cap_e - 1)].set(
-            jnp.where(keep[:, None], xs, 0), mode="drop")
-        ge = jnp.einsum("ecd,edf->ecf", xe, params["w_gate"])
-        ue = jnp.einsum("ecd,edf->ecf", xe, params["w_up"])
-        he = jax.nn.silu(ge) * ue                       # bf16 act (§Perf I5)
-        ye = jnp.einsum("ecf,efd->ecd", he, params["w_down"])
-        y = jnp.where(keep[:, None],
-                      ye[gid, jnp.minimum(pos, cap_e - 1)], 0.0)
+        with scope(MOE_EXPERTS):
+            ends = jnp.cumsum(gs)
+            row = jnp.arange(cap)
+            gid = jnp.searchsorted(ends, row, side="right")
+            gid = jnp.minimum(gid, e_loc - 1)
+            pos = row - jnp.concatenate([jnp.zeros(1, ends.dtype),
+                                         ends[:-1]])[gid]
+            keep = (row < jnp.sum(gs)) & (pos < cap_e)
+            xe = jnp.zeros((e_loc, cap_e, d), x.dtype).at[
+                jnp.where(keep, gid, e_loc - 1),
+                jnp.where(keep, pos, cap_e - 1)].set(
+                jnp.where(keep[:, None], xs, 0), mode="drop")
+            ge = jnp.einsum("ecd,edf->ecf", xe, params["w_gate"])
+            ue = jnp.einsum("ecd,edf->ecf", xe, params["w_up"])
+            he = jax.nn.silu(ge) * ue                   # bf16 act (§Perf I5)
+            ye = jnp.einsum("ecf,efd->ecd", he, params["w_down"])
+            y = jnp.where(keep[:, None],
+                          ye[gid, jnp.minimum(pos, cap_e - 1)], 0.0)
     else:
         # ---- padding-free ragged expert FFN (the paper's kernel) -------
         # Plan once per routing decision: the gate/up/down GEMMs (and the
@@ -210,8 +216,9 @@ def moe_apply(params, x, cfg: MoEConfig, *, ep_rank=0, ep_size: int = 1,
         qx = None
         if cfg.precision == "fp8":
             if dispatch.backend_uses_plan(kcfg.backend):
-                tile_plan = make_tile_plan(gs, cap, block_m=kcfg.block_m,
-                                           num_groups=e_loc)
+                with scope(MOE_PACK):
+                    tile_plan = make_tile_plan(gs, cap, block_m=kcfg.block_m,
+                                               num_groups=e_loc)
             # quantize once per routing decision, like the plan: ONE
             # 1x128 tilewise quantization of the packed buffer serves the
             # gate AND up GEMMs (and, under wgrad_precision="fp8", their
@@ -223,102 +230,117 @@ def moe_apply(params, x, cfg: MoEConfig, *, ep_rank=0, ep_size: int = 1,
             # height would come from autotune(op="quantize") and can be
             # passed here instead — the record's values are tile-height
             # independent either way, only wall time moves.
-            qx = quantize_activation(xs, backend=kcfg.backend, config=kcfg)
-        if cfg.precision == "fp8" and kcfg.fuse_producer:
-            # producer-fused FFN: the gate/up GEMMs emit fp8 + 1x128
-            # scales straight from their store phase (grouped_gemm_quant)
-            # and the activation dequantizes them on load — g/u never
-            # exist in bf16 anywhere, and the whole expert FFN performs
-            # exactly ONE standalone quantize (the qx above).  Numerics
-            # differ from the unfused recipe by one extra e4m3 rounding
-            # of g/u (see grouped_linear_ffn's docstring).
-            y = grouped_linear_ffn(xs, params["w_gate"], params["w_up"],
-                                   params["w_down"], gs, act="silu_mul",
-                                   config=kcfg, plan=tile_plan,
-                                   quantized=qx)             # [cap, d]
-        else:
-            glin = functools.partial(grouped_linear,
-                                     precision=cfg.precision,
-                                     config=kcfg, plan=tile_plan)
-            g = glin(xs, params["w_gate"], gs, quantized=qx)  # [cap, f_loc]
-            u = glin(xs, params["w_up"], gs, quantized=qx)
-            if cfg.precision == "fp8":
-                # fused epilogue: silu(g)*u + 1x128 quantization in one
-                # (act_quant, fp8) pass — the bf16 h intermediate never
-                # touches HBM and the down GEMM consumes the
-                # QuantizedActivation directly (zero standalone quantizes
-                # of h, forward and backward)
-                y = grouped_linear_fused(g, u, params["w_down"], gs,
-                                         act="silu_mul", config=kcfg,
-                                         plan=tile_plan)     # [cap, d]
+            with scope(MOE_EXPERTS), scope(QUANT_ACT):
+                qx = quantize_activation(xs, backend=kcfg.backend,
+                                         config=kcfg)
+        with scope(MOE_EXPERTS):
+            if cfg.precision == "fp8" and kcfg.fuse_producer:
+                # producer-fused FFN: the gate/up GEMMs emit fp8 + 1x128
+                # scales straight from their store phase
+                # (grouped_gemm_quant) and the activation dequantizes them
+                # on load — g/u never exist in bf16 anywhere, and the
+                # whole expert FFN performs exactly ONE standalone
+                # quantize (the qx above).  Numerics differ from the
+                # unfused recipe by one extra e4m3 rounding of g/u (see
+                # grouped_linear_ffn's docstring).
+                y = grouped_linear_ffn(xs, params["w_gate"], params["w_up"],
+                                       params["w_down"], gs, act="silu_mul",
+                                       config=kcfg, plan=tile_plan,
+                                       quantized=qx)         # [cap, d]
             else:
-                h = jax.nn.silu(g) * u                      # bf16 act (I5)
-                y = glin(h, params["w_down"], gs)           # [cap, d]
+                glin = functools.partial(grouped_linear,
+                                         precision=cfg.precision,
+                                         config=kcfg, plan=tile_plan)
+                g = glin(xs, params["w_gate"], gs, quantized=qx)  # [cap, f]
+                u = glin(xs, params["w_up"], gs, quantized=qx)
+                if cfg.precision == "fp8":
+                    # fused epilogue: silu(g)*u + 1x128 quantization in
+                    # one (act_quant, fp8) pass — the bf16 h intermediate
+                    # never touches HBM and the down GEMM consumes the
+                    # QuantizedActivation directly (zero standalone
+                    # quantizes of h, forward and backward)
+                    y = grouped_linear_fused(g, u, params["w_down"], gs,
+                                             act="silu_mul", config=kcfg,
+                                             plan=tile_plan)  # [cap, d]
+                else:
+                    h = jax.nn.silu(g) * u                  # bf16 act (I5)
+                    y = glin(h, params["w_down"], gs)       # [cap, d]
 
     # ---- combine (rows beyond `total` are defined zeros on the kernel
     # path, but hard-masking stays: it is cheap, explicit, and covers the
     # dense-dispatch branch too) ----------------------------------------
-    valid = jnp.arange(cap) < total
-    w_flat = jnp.take(weights.reshape(-1), sel)
-    contrib = jnp.where(valid[:, None],
-                        y.astype(jnp.float32) * w_flat[:, None], 0.0)
-    out = jnp.zeros((t, d), jnp.float32).at[token_of].add(
-        contrib, mode="drop")
+    with scope(MOE_COMBINE):
+        valid = jnp.arange(cap) < total
+        w_flat = jnp.take(weights.reshape(-1), sel)
+        contrib = jnp.where(valid[:, None],
+                            y.astype(jnp.float32) * w_flat[:, None], 0.0)
+        out = jnp.zeros((t, d), jnp.float32).at[token_of].add(
+            contrib, mode="drop")
 
     # ---- shared experts (TP over the axis in both modes) ---------------
     if cfg.num_shared_experts:
         fs = params["shared_gate"].shape[1]
-        if cfg.precision == "fp8" and d % 128 == 0 and fs % 128 == 0:
-            # BUGFIX: this FFN used to run bf16 ``@`` regardless of
-            # cfg.precision — the shared experts now follow the layer's
-            # precision through dense_linear_fp8 and finish with the same
-            # fused silu·mul->quantize epilogue as the routed experts.
-            # Plan-once + quantize-once, like the routed path: ONE G=1
-            # TilePlan and ONE quantization of x serve all three GEMMs.
-            splan = None
-            if dispatch.backend_uses_plan(kcfg.backend):
-                splan = make_tile_plan(jnp.array([t], jnp.int32), t,
-                                       block_m=kcfg.block_m, num_groups=1)
-            qs = quantize_activation(x, backend=kcfg.backend, config=kcfg)
-            if kcfg.fuse_producer:
-                # producer-fused shared-expert FFN — same seam as the
-                # routed experts: gate/up emit fp8 directly, one
-                # standalone quantize (qs) for the whole FFN
-                out = out + dense_ffn_fp8(
-                    x, params["shared_gate"], params["shared_up"],
-                    params["shared_down"], act="silu_mul", config=kcfg,
-                    out_dtype=jnp.float32, plan=splan, quantized=qs)
+        with scope(MOE_SHARED):
+            if cfg.precision == "fp8" and d % 128 == 0 and fs % 128 == 0:
+                # BUGFIX: this FFN used to run bf16 ``@`` regardless of
+                # cfg.precision — the shared experts now follow the
+                # layer's precision through dense_linear_fp8 and finish
+                # with the same fused silu·mul->quantize epilogue as the
+                # routed experts.  Plan-once + quantize-once, like the
+                # routed path: ONE G=1 TilePlan and ONE quantization of x
+                # serve all three GEMMs.
+                splan = None
+                if dispatch.backend_uses_plan(kcfg.backend):
+                    splan = make_tile_plan(jnp.array([t], jnp.int32), t,
+                                           block_m=kcfg.block_m,
+                                           num_groups=1)
+                with scope(QUANT_ACT):
+                    qs = quantize_activation(x, backend=kcfg.backend,
+                                             config=kcfg)
+                if kcfg.fuse_producer:
+                    # producer-fused shared-expert FFN — same seam as the
+                    # routed experts: gate/up emit fp8 directly, one
+                    # standalone quantize (qs) for the whole FFN
+                    out = out + dense_ffn_fp8(
+                        x, params["shared_gate"], params["shared_up"],
+                        params["shared_down"], act="silu_mul", config=kcfg,
+                        out_dtype=jnp.float32, plan=splan, quantized=qs)
+                else:
+                    sg = dense_linear_fp8(x, params["shared_gate"],
+                                          config=kcfg, plan=splan,
+                                          quantized=qs)
+                    su = dense_linear_fp8(x, params["shared_up"],
+                                          config=kcfg, plan=splan,
+                                          quantized=qs)
+                    out = out + dense_linear_fp8_fused(
+                        sg, su, params["shared_down"], act="silu_mul",
+                        config=kcfg, out_dtype=jnp.float32, plan=splan)
             else:
-                sg = dense_linear_fp8(x, params["shared_gate"], config=kcfg,
-                                      plan=splan, quantized=qs)
-                su = dense_linear_fp8(x, params["shared_up"], config=kcfg,
-                                      plan=splan, quantized=qs)
-                out = out + dense_linear_fp8_fused(
-                    sg, su, params["shared_down"], act="silu_mul",
-                    config=kcfg, out_dtype=jnp.float32, plan=splan)
-        else:
-            sg = x @ params["shared_gate"]
-            su = x @ params["shared_up"]
-            sh = jax.nn.silu(sg) * su                       # bf16 act (I5)
-            out = out + (sh @ params["shared_down"]).astype(jnp.float32)
+                sg = x @ params["shared_gate"]
+                su = x @ params["shared_up"]
+                sh = jax.nn.silu(sg) * su                   # bf16 act (I5)
+                out = out + (sh @ params["shared_down"]).astype(jnp.float32)
 
     if axis_name is not None:
-        out = jax.lax.psum(out.astype(cfg.reduce_dtype), axis_name) \
-            .astype(jnp.float32)
+        with scope(MOE_COMBINE):
+            out = jax.lax.psum(out.astype(cfg.reduce_dtype), axis_name) \
+                .astype(jnp.float32)
 
     # ---- aux: load-balance loss + drop stats (replicated math) ---------
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(jax.nn.one_hot(ids, e,
-                                 dtype=jnp.float32).sum(1), axis=0)
-    if axis_name is not None and ep_size > 1:
-        kept = jax.lax.psum(total, axis_name)   # shards own disjoint experts
-    else:
-        kept = total                            # TP/local: every slot local
-    aux = {
-        "load_balance_loss": e * jnp.sum(me * ce) / k,
-        "dropped_fraction": 1.0 - kept / num_slots,
-    }
-    return out.astype(x.dtype), aux
+    with scope(MOE_ROUTE):
+        me = jnp.mean(probs, axis=0)
+        ce = jnp.mean(jax.nn.one_hot(ids, e,
+                                     dtype=jnp.float32).sum(1), axis=0)
+        if axis_name is not None and ep_size > 1:
+            kept = jax.lax.psum(total, axis_name)  # disjoint experts
+        else:
+            kept = total                           # TP/local: all local
+        aux = {
+            "load_balance_loss": e * jnp.sum(me * ce) / k,
+            "dropped_fraction": 1.0 - kept / num_slots,
+        }
+    with scope(MOE_COMBINE):
+        return out.astype(x.dtype), aux
 
 
 def shard_moe_params(params, cfg: MoEConfig, ep_size: int):

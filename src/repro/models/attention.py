@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.models.layers import ninit, rope, rms_norm, init_rms_norm
 from repro.distributed.context import constrain
+from repro.scopes import ATTN, scope
 
 NEG_INF = -1e30
 
@@ -201,6 +202,7 @@ def _cache_from_prefill(k, v, window, capacity=None, dtype=jnp.bfloat16):
             "len": jnp.array(s, jnp.int32)}
 
 
+@scope(ATTN)
 def attention_block(p, x, cfg, positions, *, cache=None, layer_window=None,
                     causal=True, mode="train", cache_capacity=None):
     """Full attention sub-block.  With ``cache`` (dict k,v,len) performs

@@ -27,6 +27,7 @@ from repro.models import xlstm as xl
 from repro.models.layers import (init_rms_norm, rms_norm, init_mlp, mlp,
                                  init_embedding, embed, unembed, ninit,
                                  cross_entropy)
+from repro.scopes import DENSE_FFN, EMBED, LM_HEAD, scope
 
 
 def effective_pattern(cfg: ModelConfig):
@@ -127,9 +128,10 @@ def block_apply(kind: str, p, x, cfg: ModelConfig, positions, *,
             ff, aux = _apply_moe(p["moe"], h2, cfg)
         else:
             act = "gelu" if cfg.family == "audio" else "swiglu"
-            ff = mlp(p["mlp"], h2, act, precision=cfg.precision,
-                     backend=cfg.gemm_backend,
-                     config=cfg.resolved_kernel_config)
+            with scope(DENSE_FFN):
+                ff = mlp(p["mlp"], h2, act, precision=cfg.precision,
+                         backend=cfg.gemm_backend,
+                         config=cfg.resolved_kernel_config)
         return x + ff, new_cache, aux
     if kind == "rglru":
         h, new_state = rg.rglru_apply(
@@ -138,9 +140,11 @@ def block_apply(kind: str, p, x, cfg: ModelConfig, positions, *,
         if mode == "train":
             new_state = None
         x = x + h
-        ff = mlp(p["mlp"], rms_norm(p["ln2"], x, cfg.norm_eps), "swiglu",
-                 precision=cfg.precision, backend=cfg.gemm_backend,
-                 config=cfg.resolved_kernel_config)
+        h2 = rms_norm(p["ln2"], x, cfg.norm_eps)
+        with scope(DENSE_FFN):
+            ff = mlp(p["mlp"], h2, "swiglu", precision=cfg.precision,
+                     backend=cfg.gemm_backend,
+                     config=cfg.resolved_kernel_config)
         return x + ff, new_state, aux
     if kind == "mlstm":
         h, new_state = xl.mlstm_apply(
@@ -242,13 +246,14 @@ def decoder_forward(params, tokens, cfg: ModelConfig, *, mode="train",
     """
     pattern, n_pre, cycles, tail = _layout(cfg)
     b, s = tokens.shape
-    x = embed(params["embed"], tokens)
-    if patch_embeds is not None:
-        pe = jnp.einsum("bpe,ed->bpd", patch_embeds.astype(x.dtype),
-                        params["vision_proj"].astype(x.dtype))
-        x = jnp.concatenate([pe, x], axis=1)
-        s = x.shape[1]
-    x = dctx.constrain(x, "batch", "seq", "embed")
+    with scope(EMBED):
+        x = embed(params["embed"], tokens)
+        if patch_embeds is not None:
+            pe = jnp.einsum("bpe,ed->bpd", patch_embeds.astype(x.dtype),
+                            params["vision_proj"].astype(x.dtype))
+            x = jnp.concatenate([pe, x], axis=1)
+            s = x.shape[1]
+        x = dctx.constrain(x, "batch", "seq", "embed")
 
     if mode == "decode":
         positions = None  # per-layer caches carry the position
@@ -324,10 +329,11 @@ def decoder_forward(params, tokens, cfg: ModelConfig, *, mode="train",
         if new_cache is not None:
             new_cache[f"tail{i}"] = nc
 
-    if mode == "prefill":
-        x = x[:, -1:]        # serving prefill needs only the last position
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)
+    with scope(LM_HEAD):
+        if mode == "prefill":
+            x = x[:, -1:]    # serving prefill needs only the last position
+        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
+        logits = unembed(params["embed"], x)
     return logits, new_cache, aux_total
 
 
@@ -337,10 +343,11 @@ def lm_loss(params, batch, cfg: ModelConfig, *, aux_weight=0.01):
     logits, _, aux = decoder_forward(
         params, batch["tokens"], cfg, mode="train",
         patch_embeds=batch.get("patch_embeds"))
-    labels = batch["labels"]
-    if batch.get("patch_embeds") is not None:
-        p = batch["patch_embeds"].shape[1]
-        pad = jnp.full((labels.shape[0], p), -1, labels.dtype)
-        labels = jnp.concatenate([pad, labels], axis=1)
-    loss = cross_entropy(logits[:, :-1], labels[:, 1:])
-    return loss + aux_weight * aux, {"ce": loss, "aux": aux}
+    with scope(LM_HEAD):
+        labels = batch["labels"]
+        if batch.get("patch_embeds") is not None:
+            p = batch["patch_embeds"].shape[1]
+            pad = jnp.full((labels.shape[0], p), -1, labels.dtype)
+            labels = jnp.concatenate([pad, labels], axis=1)
+        loss = cross_entropy(logits[:, :-1], labels[:, 1:])
+        return loss + aux_weight * aux, {"ce": loss, "aux": aux}

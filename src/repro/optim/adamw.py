@@ -13,6 +13,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.scopes import OPTIMIZER, scope
+
 
 @dataclasses.dataclass(frozen=True)
 class OptConfig:
@@ -68,6 +70,7 @@ def _compress_int8(g, ef):
     return deq, t - deq
 
 
+@scope(OPTIMIZER)
 def apply_updates(params, grads, state, cfg: OptConfig):
     """Returns (new_params, new_state, metrics)."""
     step = state["step"] + 1

@@ -18,7 +18,6 @@ paper's configure-once/select-cheaply descriptor pool.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Optional
 
 import jax
@@ -29,6 +28,7 @@ from repro.kernels import plan as plan_mod
 from repro.kernels.plan import KernelConfig
 from repro.models import model_zoo
 from repro.models.model_zoo import Model
+from repro.scopes import ENGINE_DECODE, ENGINE_PREFILL, ENGINE_SAMPLE, span
 
 
 @dataclasses.dataclass
@@ -68,9 +68,8 @@ class Engine:
         self.max_new = max_new_tokens
         self.eos_id = eos_id
         self.temperature = temperature
-        self._prefill = jax.jit(
-            functools.partial(self._prefill_impl),
-            static_argnames=("cache_capacity",))
+        self._prefill = jax.jit(self._prefill_impl,
+                                static_argnames=("cache_capacity",))
         self._decode_loop = jax.jit(self._decode_loop_impl)
 
     @staticmethod
@@ -132,13 +131,16 @@ class Engine:
         extra = (self.model.cfg.num_patches
                  if self.model.cfg.family == "vlm" else 0)
         cap = prompt_len + extra + self.max_new
-        last_logits, cache = self._prefill(self.params, batch,
-                                           cache_capacity=cap)
-        key, sub = jax.random.split(key)
-        first = self._sample(last_logits, sub)
-        rest, done = self._decode_loop(self.params, first, cache, key)
-        tokens = jnp.concatenate([first[:, None], rest], axis=1)
-        num = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
+        with span(ENGINE_PREFILL):
+            last_logits, cache = self._prefill(self.params, batch,
+                                               cache_capacity=cap)
+        with span(ENGINE_SAMPLE):
+            key, sub = jax.random.split(key)
+            first = self._sample(last_logits, sub)
+        with span(ENGINE_DECODE):
+            rest, done = self._decode_loop(self.params, first, cache, key)
+            tokens = jnp.concatenate([first[:, None], rest], axis=1)
+            num = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
         return GenerationResult(tokens=tokens, num_generated=num)
 
 
