@@ -40,6 +40,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import events as _events
 from repro.kernels import dispatch
 from repro.kernels import ref as kref
 from repro.kernels.plan import KernelConfig, TilePlan, make_tile_plan, \
@@ -57,7 +58,19 @@ def _quant_act(x, config):
 
 def _quant_weights(w, config, *, transpose=False):
     """128x128-block quantization of ``w`` [G, K, N] (of each group's
-    transpose with ``transpose``), f32 upcast included."""
+    transpose with ``transpose``), f32 upcast included.  A
+    :class:`~repro.core.quantization.QuantizedWeight` is already that
+    quantization and passes through."""
+    if isinstance(w, q.QuantizedWeight):
+        if transpose:
+            raise ValueError(
+                "a QuantizedWeight is a serving-only, forward-only weight: "
+                "the backward needs the raw weight to quantize its "
+                "transpose; differentiate through the raw params instead")
+        return w.q, w.scale
+    # one event per weight quantization traced — a served program that
+    # consumes pre-quantized weights traces none
+    _events.emit("quantize_blockwise", shape=tuple(w.shape))
     with scope(QUANT_WEIGHTS):
         if transpose:
             w = jnp.swapaxes(w, 1, 2)
@@ -491,6 +504,12 @@ def grouped_linear(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
     raise ValueError(f"unknown precision {precision!r}")
 
 
+def _one_group(w):
+    """A dense [K, N] weight (raw or a ``QuantizedWeight``) as the G=1
+    grouped weight [1, K, N]."""
+    return jax.tree.map(lambda a: a[None], w)
+
+
 def dense_linear_fp8(x: jax.Array, w: jax.Array, *,
                      backend: str | None = None,
                      out_dtype: Any = None,
@@ -508,7 +527,7 @@ def dense_linear_fp8(x: jax.Array, w: jax.Array, *,
     pair) amortize one G=1 TilePlan and one quantization."""
     m = x.shape[0]
     gs = jnp.array([m], jnp.int32)
-    return grouped_linear(x, w[None], gs, precision="fp8",
+    return grouped_linear(x, _one_group(w), gs, precision="fp8",
                           backend=backend, out_dtype=out_dtype,
                           config=config, plan=plan, quantized=quantized)
 
@@ -569,8 +588,9 @@ def dense_linear_fp8_fused(g: jax.Array, u: jax.Array | None,
     g2 = g.reshape(-1, f)
     u2 = None if u is None else u.reshape(-1, f)
     gs = jnp.array([g2.shape[0]], jnp.int32)
-    y = grouped_linear_fused(g2, u2, w[None], gs, act=act, backend=backend,
-                             out_dtype=out_dtype, config=config, plan=plan)
+    y = grouped_linear_fused(g2, u2, _one_group(w), gs, act=act,
+                             backend=backend, out_dtype=out_dtype,
+                             config=config, plan=plan)
     return y.reshape(*lead, w.shape[-1])
 
 
@@ -636,8 +656,8 @@ def dense_ffn_fp8(x: jax.Array, w_gate: jax.Array | None, w_up: jax.Array,
     x2 = x.reshape(-1, k)
     gs = jnp.array([x2.shape[0]], jnp.int32)
     y = grouped_linear_ffn(
-        x2, None if w_gate is None else w_gate[None], w_up[None],
-        w_down[None], gs, act=act, backend=backend, out_dtype=out_dtype,
+        x2, None if w_gate is None else _one_group(w_gate), _one_group(w_up),
+        _one_group(w_down), gs, act=act, backend=backend, out_dtype=out_dtype,
         config=config, plan=plan, quantized=quantized)
     return y.reshape(*lead, w_down.shape[-1])
 

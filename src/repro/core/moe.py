@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from repro.core.grouped_gemm import (dense_ffn_fp8, dense_linear_fp8,
                                      dense_linear_fp8_fused, grouped_linear,
                                      grouped_linear_ffn, grouped_linear_fused)
-from repro.core.quantization import quantize_activation
+from repro.core.quantization import quantize_activation, quantize_weight
 from repro.kernels import dispatch
 from repro.kernels.plan import KernelConfig, make_tile_plan, resolve_config
 from repro.scopes import (MOE_COMBINE, MOE_EXPERTS, MOE_PACK, MOE_ROUTE,
@@ -122,6 +122,12 @@ def _capacity(num_slots: int, ep_size: int, cf: float,
     cap_all = -(-num_slots // align) * align      # aligned ceiling
     c = -(-int(num_slots / ep_size * cf) // align) * align
     return min(cap_all, max(c, align))
+
+
+def _shared_fp8(cfg: MoEConfig, d: int, fs: int) -> bool:
+    """Whether the shared-expert FFN of width ``fs`` over ``d`` takes the
+    fp8 G=1 path: both widths must tile into 128x128 weight blocks."""
+    return cfg.precision == "fp8" and d % 128 == 0 and fs % 128 == 0
 
 
 def moe_apply(params, x, cfg: MoEConfig, *, ep_rank=0, ep_size: int = 1,
@@ -281,7 +287,7 @@ def moe_apply(params, x, cfg: MoEConfig, *, ep_rank=0, ep_size: int = 1,
     if cfg.num_shared_experts:
         fs = params["shared_gate"].shape[1]
         with scope(MOE_SHARED):
-            if cfg.precision == "fp8" and d % 128 == 0 and fs % 128 == 0:
+            if _shared_fp8(cfg, d, fs):
                 # BUGFIX: this FFN used to run bf16 ``@`` regardless of
                 # cfg.precision — the shared experts now follow the
                 # layer's precision through dense_linear_fp8 and finish
@@ -341,6 +347,31 @@ def moe_apply(params, x, cfg: MoEConfig, *, ep_rank=0, ep_size: int = 1,
         }
     with scope(MOE_COMBINE):
         return out.astype(x.dtype), aux
+
+
+def quantize_serving_weights(params, cfg: MoEConfig):
+    """The MoE params with every weight that ``moe_apply`` quantizes to
+    fp8 replaced by its :class:`~repro.core.quantization.QuantizedWeight`
+    — the same values the layer would compute from the raw weight, so the
+    served programs skip the quantization and hold fp8 weights only.
+
+    The routed experts on the fp8 ragged path, and the shared experts
+    where ``moe_apply`` takes their fp8 path; the tree is returned
+    unchanged when ``cfg.precision`` is not fp8.  Leading (stacked-layer)
+    axes are kept.  Forward only: gradients need the raw weights."""
+    if cfg.precision != "fp8":
+        return params
+    backend = resolve_config(cfg.kernel_config, backend=cfg.backend).backend
+    names = []
+    if cfg.dispatch != "dense":          # the dense dispatch runs bf16
+        names += ["w_gate", "w_up", "w_down"]
+    if cfg.num_shared_experts and _shared_fp8(
+            cfg, *params["shared_gate"].shape[-2:]):
+        names += ["shared_gate", "shared_up", "shared_down"]
+    out = dict(params)
+    for name in names:
+        out[name] = quantize_weight(params[name], backend=backend)
+    return out
 
 
 def shard_moe_params(params, cfg: MoEConfig, ep_size: int):

@@ -10,10 +10,16 @@ entry points used by the training path.
 GEMM consuming the same buffer (the MoE gate and up projections, and —
 under ``wgrad_precision="fp8"`` — the backward's wgrad via the VJP
 residual) amortizes the quantization like the schedule metadata.
+
+:class:`QuantizedWeight` is its serving counterpart for weights: the
+128x128-block fp8 form of a weight that does not change, built once by
+:func:`quantize_weight` (the serving engine does so at construction) and
+passed to the fp8 GEMMs in place of the raw weight.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +27,7 @@ import jax.numpy as jnp
 from repro.analysis import events as _events
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.scopes import QUANT_WEIGHTS, scope
 
 QUANT_BLOCK = kref.QUANT_BLOCK
 FP8_MAX = kref.FP8_MAX
@@ -50,6 +57,47 @@ jax.tree_util.register_pytree_node(
     QuantizedActivation,
     lambda qa: ((qa.q, qa.scale), None),
     lambda _, children: QuantizedActivation(*children))
+
+
+@dataclasses.dataclass(frozen=True)
+class QuantizedWeight:
+    """128x128-block fp8 representation of one constant weight.
+
+    ``q``: [..., K, N] fp8 e4m3; ``scale``: [..., ceil(K/128),
+    ceil(N/128)] f32 — exactly what the fp8 GEMMs' own weight quantization
+    computes from the raw weight.  Leading axes (experts, stacked layers)
+    index both leaves alike; a registered pytree, so a ``scan`` over
+    stacked layers slices it like any other leaf.  Forward only: the
+    backward needs the raw weight, so a gradient through a record raises.
+    """
+    q: jax.Array       # [..., K, N] fp8 e4m3
+    scale: jax.Array   # [..., ceil(K/128), ceil(N/128)] f32
+
+    @property
+    def shape(self):
+        """The raw weight's shape (the payload's)."""
+        return self.q.shape
+
+
+jax.tree_util.register_pytree_node(
+    QuantizedWeight,
+    lambda qw: ((qw.q, qw.scale), None),
+    lambda _, children: QuantizedWeight(*children))
+
+
+@functools.partial(jax.jit, static_argnames=("backend",))
+def quantize_weight(w, *, backend=None) -> QuantizedWeight:
+    """ONE 128x128-block quantization of ``w`` [..., K, N] into a
+    :class:`QuantizedWeight`: the f32 upcast and
+    :func:`quantize_blockwise_batched` the fp8 GEMMs apply to a raw
+    weight, so the record holds the same values bitwise.  One jitted call
+    per weight keeps a single f32 upcast live at a time."""
+    with scope(QUANT_WEIGHTS):
+        lead, (k, n) = w.shape[:-2], w.shape[-2:]
+        q8, s = quantize_blockwise_batched(
+            w.reshape(-1, k, n).astype(jnp.float32), backend=backend)
+        return QuantizedWeight(q8.reshape(*lead, *q8.shape[1:]),
+                               s.reshape(*lead, *s.shape[1:]))
 
 
 def quantize_activation(x, *, backend=None, config=None) -> QuantizedActivation:

@@ -19,7 +19,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.moe import (MoEConfig, init_moe_params, moe_apply,
-                            ep_size_for, shard_moe_params)
+                            ep_size_for, quantize_serving_weights,
+                            shard_moe_params)
 from repro.distributed import context as dctx
 from repro.models import attention as attn
 from repro.models import rglru as rg
@@ -82,12 +83,11 @@ def init_block(key, kind: str, cfg: ModelConfig, *, moe_layer: bool):
 def _apply_moe(p, x, cfg: ModelConfig):
     mcfg = moe_config(cfg)
     b, s, d = x.shape
-    mesh = dctx.get_mesh()
-    if mesh is None or "model" not in mesh.axis_names \
-            or mesh.shape["model"] == 1:
+    if dctx.model_axis_size() == 1:
         y, aux = moe_apply(p, x.reshape(b * s, d), mcfg)
         return y.reshape(b, s, d), aux["load_balance_loss"]
 
+    mesh = dctx.get_mesh()
     ep = ep_size_for(mcfg, mesh.shape["model"])
     pspecs = shard_moe_params(p, mcfg, ep)
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -103,6 +103,28 @@ def _apply_moe(p, x, cfg: ModelConfig):
     y, lb = jax.shard_map(local_fn, mesh=mesh, in_specs=(pspecs, xspec),
                           out_specs=(xspec, P()), check_vma=False)(p, x)
     return y, lb
+
+
+def quantize_serving_params(params, cfg: ModelConfig):
+    """``params`` with each MoE block's fp8 weights quantized once
+    (:func:`~repro.core.moe.quantize_serving_weights`), for serving.
+
+    Unchanged for a model without fp8 MoE blocks, and while a mesh with
+    a ``model`` axis over 1 is active: the sharded MoE path
+    (``shard_moe_params``) places raw weights only."""
+    if cfg.moe is None or cfg.precision != "fp8" \
+            or dctx.model_axis_size() > 1:
+        return params
+    mcfg = moe_config(cfg)
+
+    def walk(tree):
+        if isinstance(tree, dict):
+            return {k: quantize_serving_weights(v, mcfg) if k == "moe"
+                    else walk(v) for k, v in tree.items()}
+        if isinstance(tree, list):
+            return [walk(v) for v in tree]
+        return tree
+    return walk(params)
 
 
 def block_apply(kind: str, p, x, cfg: ModelConfig, positions, *,

@@ -14,6 +14,13 @@ separate tuned tile geometries while sharing one param tree.  Inside the
 jitted decode loop the TilePlan schedule is then traced once and replayed
 every step — one plan build per phase, the serving analogue of the
 paper's configure-once/select-cheaply descriptor pool.
+
+Weights are quantized once, too: the engine replaces every fp8 MoE
+weight by its 128x128-block fp8 payload and scales at construction
+(``transformer.quantize_serving_params``), as the paper configures its
+descriptor pool once, so the compiled prefill and decode programs take
+fp8 weights as inputs and quantize none.  The engine keeps only the
+quantized tree; the raw expert weights are the caller's to drop.
 """
 from __future__ import annotations
 
@@ -23,11 +30,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.quantization import QuantizedWeight
 from repro.kernels import dispatch
 from repro.kernels import plan as plan_mod
 from repro.kernels.plan import KernelConfig
 from repro.models import model_zoo
 from repro.models.model_zoo import Model
+from repro.models.transformer import quantize_serving_params
 from repro.scopes import ENGINE_DECODE, ENGINE_PREFILL, ENGINE_SAMPLE, span
 
 
@@ -64,7 +73,11 @@ class Engine:
         self._decode_model = (
             model_zoo.with_kernel_config(model, self.decode_config)
             if self.decode_config is not None else model)
-        self.params = params
+        self.params = quantize_serving_params(params, model.cfg)
+        # weights served pre-quantized (0 for bf16 or non-MoE models)
+        self.quantized_weights = sum(
+            isinstance(v, QuantizedWeight) for v in jax.tree.leaves(
+                self.params, is_leaf=lambda v: isinstance(v, QuantizedWeight)))
         self.max_new = max_new_tokens
         self.eos_id = eos_id
         self.temperature = temperature
