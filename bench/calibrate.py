@@ -104,7 +104,8 @@ def calibrate_serve(cell, seeds, control_seeds, rehearsal, backend):
     prog = serve.build(cell.config, backend, rehearsal, seeds[0], t)
     table = {"program": [], "control": []}
     for seed in sorted(set(seeds) | set(control_seeds)):
-        prog.engine.params = weights.make(prog.shapes, seed)
+        prog.engine.params = weights.make(prog.shapes, seed,
+                                          init=cell.config.get("init"))
         pool = workload.pool(t, prog.cfg.vocab_size, seed)
         # the cell's own load: two calls, sampled as a run samples
         done = [(i, 0.0, np.asarray(prog.engine.generate(pool[i]).tokens))

@@ -31,7 +31,8 @@ def build(config: dict, backend: str, rehearsal: bool, seed: int,
     model = make_model(cfg)
     shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     eng = Engine(
-        model, weights.make(shapes, seed), max_new_tokens=traffic["new"],
+        model, weights.make(shapes, seed, init=config.get("init")),
+        max_new_tokens=traffic["new"],
         kernel_config=KernelConfig(backend=backend),
         decode_kernel_config=KernelConfig(backend=backend,
                                           block_m=config["decode_block_m"]))
@@ -88,7 +89,8 @@ def reference_gaps(config: dict, prog_cfg, shapes, seed: int, prompts,
     first, at the same positions."""
     ref = spec.reference(config["reference"])
     sz = spec.sizes(prog_cfg)
-    params = weights.make(shapes, seed, dtype=jnp.float32)
+    params = weights.make(shapes, seed, dtype=jnp.float32,
+                          init=config.get("init"))
     prompts, served = jnp.asarray(prompts), jnp.asarray(served)
     logits, rows = ref.served_logits(params, prompts, served, sz)
     if control:

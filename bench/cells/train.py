@@ -23,6 +23,7 @@ class Program:
     opt_cfg: object
     compiled: object
     resolutions: list     # (family, precision, backend) of every GEMM
+    init: dict            # the configuration file's ``init`` ({} if none)
 
 
 def leaf_names(shapes) -> list:
@@ -65,13 +66,14 @@ def compile_program(config: dict, traffic: dict, backend: str,
                              {"tokens": tok, "labels": tok})
     res = [(e.data["family"], e.data["precision"], e.data["backend"])
            for e in events if e.kind == "backend_resolved"]
-    return Program(cfg, shapes, opt_cfg, lowered.compile(), res)
+    return Program(cfg, shapes, opt_cfg, lowered.compile(), res,
+                   config.get("init", {}))
 
 
 def init_state(prog: Program, seed: int):
     """Params from the seed, AdamW state made on the device."""
     from repro.optim import adamw
-    params = weights.make(prog.shapes, seed)
+    params = weights.make(prog.shapes, seed, init=prog.init)
     opt = jax.jit(functools.partial(adamw.init_opt_state,
                                     cfg=prog.opt_cfg))(params)
     return params, opt
@@ -101,7 +103,7 @@ def first_steps(prog: Program, params, opt, pool, seed: int, n: int):
         losses.append(met["loss"])
         if i == 0:
             grad_norms = _norms(opt["m"])
-    start = weights.make(prog.shapes, seed)
+    start = weights.make(prog.shapes, seed, init=prog.init)
     change = _change_norms(opt["master"], start)
     del start
     scale = 1.0 / (1.0 - prog.opt_cfg.b1)
@@ -141,7 +143,8 @@ def reference_readings(config: dict, prog: Program, pool_host: list,
     ref = spec.reference(config["reference"])
     sz = spec.sizes(prog.cfg)
     return ref.train_readings(
-        lambda: weights.make(prog.shapes, seed, dtype=jnp.float32),
+        lambda: weights.make(prog.shapes, seed, dtype=jnp.float32,
+                             init=prog.init),
         [jax.device_put(b) for b in pool_host], sz, config["optimizer"],
         config["aux_loss_weight"], control=control)
 

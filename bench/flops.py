@@ -1,6 +1,13 @@
 """Model FLOPs and the least work of the expert GEMMs, from the
 configuration's shapes alone (``spec.sizes``): never from tiles, padding
-or what the program happens to compute."""
+or what the program happens to compute.
+
+Where a configuration holds one share of a layer's experts (``moe``'s
+``num_experts`` held of ``router_experts`` scored), this chip's routed
+work is the held experts' share of every token's ``top_k`` slots under
+balanced routing, ``top_k x num_experts / router_experts``: the load a
+deployment's router is trained toward.  Rows that a skewed routing sends
+to the held experts beyond that share are not credited as least work."""
 from __future__ import annotations
 
 # bytes per element of the dtypes the configuration states
@@ -12,19 +19,31 @@ def _moe_layers(sz) -> int:
     return sz["num_layers"] - sz["moe"]["first_dense_layers"]
 
 
+def _router_experts(m) -> int:
+    return m.get("router_experts", m["num_experts"])
+
+
+def _held_slots(m):
+    """Routed slots of one token that land on the held experts under
+    balanced routing: ``top_k`` where every expert is held."""
+    return m["top_k"] * m["num_experts"] / _router_experts(m)
+
+
 def matmul_params(sz) -> dict:
-    """Matmul parameters a token passes through, by kind, summed over
-    layers.  The embedding lookup is no matmul and does not count."""
+    """Matmul parameters a token passes through on this chip, by kind,
+    summed over layers: the router scores all ``router_experts``, the
+    routed experts count for the held share of ``top_k`` (module
+    docstring).  The embedding lookup is no matmul and does not count."""
     d, hd = sz["d_model"], sz["head_dim"]
     m = sz["moe"]
     attn = d * hd * (2 * sz["num_heads"] + 2 * sz["num_kv_heads"])
     n_moe = _moe_layers(sz)
-    routed = 3 * d * m["d_ff_expert"] * m["top_k"]
+    routed = 3 * d * m["d_ff_expert"] * _held_slots(m)
     shared = 3 * d * m["d_ff_expert"] * m["num_shared_experts"]
     return {
         "attention": sz["num_layers"] * attn,
         "dense_ffn": m["first_dense_layers"] * 3 * d * sz["d_ff"],
-        "router": n_moe * d * m["num_experts"],
+        "router": n_moe * d * _router_experts(m),
         "routed_experts": n_moe * routed,
         "shared_experts": n_moe * shared,
         "unembedding": d * sz["vocab_size"],
@@ -88,16 +107,17 @@ def _ffn_gemms(rows: int, groups: int, d: int, f: int):
 def expert_gemm_least_s(sz, tokens: int, peak: dict, *,
                         backward: bool) -> float:
     """Least time of one pass's fp8 expert GEMMs over ``tokens`` tokens
-    in every MoE layer: routed experts over their ``tokens * top_k`` valid
-    rows (no row is dropped on one chip) and the shared experts (one group
-    over ``tokens`` rows) when their widths allow fp8.  Forward: fp8
+    in every MoE layer: the held routed experts over their share of the
+    ``tokens * top_k`` valid rows (all of them where every expert is held;
+    no row is dropped on one chip) and the shared experts (one group over
+    ``tokens`` rows) when their widths allow fp8.  Forward: fp8
     operands, bf16 output.  With ``backward``: also the dgrad (fp8
     operands, f32 output) and the wgrad (bf16 operands, f32 output) of
     every GEMM, the configuration's training recipe."""
     m = sz["moe"]
     d, f = sz["d_model"], m["d_ff_expert"]
     fs = f * m["num_shared_experts"]
-    ffns = [(tokens * m["top_k"], m["num_experts"], f)]
+    ffns = [(tokens * _held_slots(m), m["num_experts"], f)]
     if fs and d % 128 == 0 and fs % 128 == 0:
         ffns.append((tokens, 1, fs))
     total = 0.0

@@ -6,11 +6,12 @@ device time of the program's named layers from the same trace.
 
 The first line printed is ``bench/run.py``'s result line for the run.
 The last line adds, under ``layers``, the layer metrics of the cell's
-kind (``bench/metrics/{moe,attn}_ms.<kind>.py``,
+kind (``bench/metrics/{moe,attn,shared}_ms.<kind>.py``,
 ``weight_quant_ms.serve.py``; null where the program names no layers),
-the share of device busy time in leaf ops with no scope and the largest
-of them, and the idle gaps labelled by the innermost host span, the
-engine's ``engine.*`` spans included (``bench/scopes.py``).
+read from the run's own reduction of its trace and context, the share of
+device busy time in leaf ops with no scope and the largest of them, and
+the run's idle gaps, labelled by the innermost host span, the engine's
+``engine.*`` spans included.
 ``--dump-trace PATH`` writes the (module, scope) -> count, seconds table
 and the op time per (module, op); ``--hlo-out DIR`` writes the optimized
 HLO of the window's programs, for comparing two commits' programs.
@@ -21,12 +22,11 @@ import argparse
 import json
 import os
 import sys
-import types
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-METRICS = {"train": ("moe_ms.train", "attn_ms.train"),
-           "serve": ("moe_ms.serve", "attn_ms.serve",
+METRICS = {"train": ("moe_ms.train", "attn_ms.train", "shared_ms.train"),
+           "serve": ("moe_ms.serve", "attn_ms.serve", "shared_ms.serve",
                      "weight_quant_ms.serve")}
 
 
@@ -46,7 +46,7 @@ def main(argv=None) -> int:
     sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
 
     import jax
-    from bench import kernels, run, scopes, spec, trace_reduce
+    from bench import run, scopes, spec
     cell = spec.cell(args.workload)
     kind = cell.traffic["kind"]
     if not args.cpu_rehearsal:
@@ -60,36 +60,20 @@ def main(argv=None) -> int:
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
-    # the runner's trace is read here before run_cell reduces and
-    # deletes it
     seen = {}
-    runner = run.RUNNERS[kind]
-
-    def keep(*a):
-        r = runner(*a)
-        seen["r"] = r
-        seen["layered"] = scopes.reduce(
-            trace_reduce.find_xplane(r["trace_dir"]),
-            require_device=not args.cpu_rehearsal)
-        return r
-
-    run.RUNNERS[kind] = keep
     result = run.run_cell(args.workload, args.seed, args.seconds, True,
-                          args.cpu_rehearsal)
+                          args.cpu_rehearsal, keep=seen)
     result.pop("_verdict")
     print(json.dumps(result), flush=True)
 
-    r, layered = seen["r"], seen["layered"]
-    programs = {k: kernels.module_name(v) for k, v in r["hlo"].items()}
-    att = scopes.attribution(layered, programs, r["hlo"])
-    ctx = types.SimpleNamespace(kind=kind, traffic=r["traffic"],
-                                trace=layered, scopes=att["chains"],
-                                programs=programs, **r["counts"])
+    r, ctx = seen["r"], seen["ctx"]
+    red = ctx.trace
     metrics = {name: spec.metric_reader(name)(ctx) for name in METRICS[kind]}
+    att = scopes.attribution(red, ctx)
     share = att["unscoped_share"]
     run.log(f"[scopes] vocabulary={len(scopes.vocabulary())} "
             f"unscoped_share={'-' if share is None else f'{share:.3f}%'} "
-            f"busy_s={layered.busy_s:.6f} "
+            f"busy_s={red.busy_s:.6f} "
             f"top_unscoped={json.dumps(att['unscoped_top'])}")
     if args.dump_trace:
         with open(args.dump_trace, "w") as f:
@@ -97,7 +81,7 @@ def main(argv=None) -> int:
                                  in sorted(att["table"].items(),
                                            key=lambda kv: -kv[1][1])],
                        "module_ops": [[m, op, n, s] for (m, op), (n, s)
-                                      in layered.module_ops.items()]},
+                                      in red.module_ops.items()]},
                       f, indent=0)
     if args.hlo_out:
         os.makedirs(args.hlo_out, exist_ok=True)
@@ -107,7 +91,7 @@ def main(argv=None) -> int:
     print(json.dumps({"layers": {
         "metrics": metrics, "unscoped_share": share,
         "unscoped_top": att["unscoped_top"],
-        "idle_gaps": [list(g) for g in layered.idle_gaps]}}), flush=True)
+        "idle_gaps": [list(g) for g in red.idle_gaps]}}), flush=True)
     return 0
 
 

@@ -100,6 +100,27 @@ def peak_bytes(devices) -> int | None:
     return max(peaks) if peaks else None
 
 
+def layer_context(kind: str, r: dict, chips: int, reduced, peak):
+    """What a per-layer metric reader may read: the run's counts
+    (``steps``, ``tokens``, ``calls``), its traffic and sizes, the peak
+    table row, the trace's ``Reduced``, and from the optimized HLO the
+    window's programs ran (``r["hlo"]``, program key -> text) their module
+    names (``programs``), expert GEMM ops (``gemm_ops``), each leaf
+    instruction's chain of layer scopes (``scopes``: program key ->
+    instruction -> chain) and their container instructions
+    (``containers``)."""
+    from bench import kernels, scopes, spec
+    parsed = {k: scopes.hlo_chains(v) for k, v in r["hlo"].items()}
+    return types.SimpleNamespace(
+        kind=kind, sizes=spec.sizes(r["prog_cfg"]), traffic=r["traffic"],
+        chips=chips, peak=peak, trace=reduced,
+        gemm_ops=kernels.expert_gemm_ops(r["hlo"].values()),
+        programs={k: kernels.module_name(v) for k, v in r["hlo"].items()},
+        scopes={k: p.chains for k, p in parsed.items()},
+        containers={k: p.containers for k, p in parsed.items()},
+        **r["counts"])
+
+
 def _share_of_peak(name: str) -> bool:
     return "roofline" in name or "mfu" in name
 
@@ -271,13 +292,15 @@ RUNNERS = {"train": run_train, "serve": run_serve}
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              rehearsal: bool, dump: str | None = None,
-             limits: dict | None = None) -> dict:
+             limits: dict | None = None, keep: dict | None = None) -> dict:
     """Set-up, window, reference; returns the result object, with the
     comparison's own verdict under ``_verdict``.  The caller has checked
     the devices.  ``limits`` replaces the cell's limit file (the tests'
-    rehearsal sizes have limits of their own)."""
+    rehearsal sizes have limits of their own).  A traced run puts the
+    runner's record (``r``) and the readers' context (``ctx``) in
+    ``keep``."""
     import jax
-    from bench import check, kernels, peaks, spec, trace_reduce
+    from bench import check, peaks, scopes, spec, trace_reduce
     cell = spec.cell(workload)
     if cell.chips != 1:
         # both runners drive one device: a multi-chip cell needs a runner
@@ -299,23 +322,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     metrics, breakdown = {}, None
     if trace:
         xplane = trace_reduce.find_xplane(r["trace_dir"])
-        red = trace_reduce.reduce(xplane, require_device=not rehearsal)
+        red = trace_reduce.reduce(xplane, require_device=not rehearsal,
+                                  host_spans=scopes.host_spans())
         if dump:
             with open(dump, "w") as f:
                 json.dump({"ops": red.ops, "modules": red.modules,
                            **trace_reduce.describe(xplane)}, f, indent=1)
         shutil.rmtree(r["trace_dir"], ignore_errors=True)
         device.update(busy_s=red.busy_s, window_s=red.window_s)
+        ctx = layer_context(cell.traffic["kind"], r, cell.chips, red,
+                            None if rehearsal else peaks.peak(device["kind"]))
+        if keep is not None:
+            keep.update(r=r, ctx=ctx)
         if not rehearsal:
-            # what a per-layer metric reader may read
-            ctx = types.SimpleNamespace(
-                kind=cell.traffic["kind"], sizes=spec.sizes(r["prog_cfg"]),
-                traffic=r["traffic"], chips=cell.chips,
-                peak=peaks.peak(device["kind"]), trace=red,
-                gemm_ops=kernels.expert_gemm_ops(r["hlo"].values()),
-                programs={k: kernels.module_name(v)
-                          for k, v in r["hlo"].items()},
-                **r["counts"])
             metrics = per_layer(cell, ctx)
         breakdown = {"device_ops": trace_reduce.top_ops(red),
                      "idle_gaps": [list(g) for g in red.idle_gaps]}

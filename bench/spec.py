@@ -103,7 +103,12 @@ def model_config(config: dict, backend: str, rehearsal: bool = False):
 
 def sizes(cfg) -> dict:
     """The numbers of a ``ModelConfig`` under the configuration file's
-    keys: what the reference and the FLOP counts read."""
+    keys: what the reference and the FLOP counts read.  ``num_experts``
+    is the count of experts the program holds; ``router_experts`` the
+    count its router scores, which is larger where the configuration
+    holds one share of a layer's experts (the program's
+    ``moe.router_experts`` where it has that field set, else
+    ``num_experts``)."""
     m = cfg.moe
     return {
         "num_layers": cfg.num_layers, "d_model": cfg.d_model,
@@ -112,7 +117,10 @@ def sizes(cfg) -> dict:
         "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
         "norm_eps": cfg.norm_eps, "qkv_bias": cfg.qkv_bias,
         "tie_embeddings": cfg.tie_embeddings,
-        "moe": {"num_experts": m.num_experts, "top_k": m.top_k,
+        "moe": {"num_experts": m.num_experts,
+                "router_experts": (getattr(m, "router_experts", None)
+                                   or m.num_experts),
+                "top_k": m.top_k,
                 "d_ff_expert": m.d_ff_expert,
                 "num_shared_experts": m.num_shared_experts,
                 "norm_topk_prob": m.norm_topk_prob,

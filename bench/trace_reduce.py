@@ -1,15 +1,22 @@
 """Reduce a profiler trace (``.xplane.pb``) to what the per-layer metrics
-read: device busy time, time per XLA module and per device op name, and
-the idle gaps labelled by the host span open at the time.
+read, in one pass: device busy time, time per XLA module, per device op
+name and per (module, op), and the idle gaps labelled by the host span
+open at the time.
 
 Planes whose name starts with ``/device:`` are devices; on each, the line
 ``XLA Ops`` holds one event per executed op and ``XLA Modules`` one per
 executed program.  Host spans are the ``bench.*`` annotations the harness
-writes (``jax.profiler.TraceAnnotation``) on the host planes.  Every
+writes (``jax.profiler.TraceAnnotation``) on the host planes, and any
+further span names the caller gives (the engine's ``engine.*``).  Every
 number is clipped to the traced window, the span ``bench.window``.
+
+An instruction name is unique in its module but not across modules, so
+``module_ops`` keys op time by (module event, op): each op event goes to
+the module event on the same device that contains its start.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import glob
@@ -27,7 +34,9 @@ class Reduced:
     devices: int
     ops: dict                       # HLO op name -> [count, device seconds]
     modules: dict                   # module name -> [count, device seconds]
-    idle_gaps: list                 # [(host span, seconds)], longest first
+    module_ops: dict                # (module event, op) -> [count, seconds]
+    idle_gaps: list                 # [(innermost host span, seconds)],
+                                    # longest first
 
     def ops_matching(self, predicate) -> tuple:
         """(count, seconds) summed over the op names ``predicate`` accepts."""
@@ -97,11 +106,24 @@ def op_name(event_name: str) -> str:
     return event_name
 
 
-def reduce(path: str, top_gaps: int = 10,
-           require_device: bool = True) -> Reduced:
+def _module_of(starts, mods, s):
+    """The name of the module event in ``mods`` (name, start, end; by
+    start) that holds time ``s``, else None."""
+    i = bisect.bisect_right(starts, s) - 1
+    if i >= 0 and s < mods[i][2]:
+        return mods[i][0]
+    return None
+
+
+def reduce(path: str, top_gaps: int = 10, require_device: bool = True,
+           host_spans=()) -> Reduced:
+    """The trace's numbers in the traced window.  An idle gap is labelled
+    by the ``bench.*`` or ``host_spans`` span that is innermost (the
+    shortest open) for the largest part of it."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
-    host_spans, device_lines = [], []
+    extra = set(host_spans)
+    spans, device_lines = [], []
     for plane in data.planes:
         lines = {ln.name: ln for ln in plane.lines}
         if plane.name.startswith("/device:"):
@@ -110,9 +132,9 @@ def reduce(path: str, top_gaps: int = 10,
                                      lines.get(MODULES_LINE)))
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
-                host_spans += [ev for ev in _events(ln)
-                               if ev[0].startswith("bench.")]
-    windows = [(s, e) for n, s, e in host_spans if n == WINDOW]
+                spans += [ev for ev in _events(ln)
+                          if ev[0].startswith("bench.") or ev[0] in extra]
+    windows = [(s, e) for n, s, e in spans if n == WINDOW]
     if len(windows) != 1:
         raise ValueError(f"{len(windows)} '{WINDOW}' spans in {path}")
     lo, hi = windows[0]
@@ -121,40 +143,55 @@ def reduce(path: str, top_gaps: int = 10,
 
     ops = collections.defaultdict(lambda: [0, 0.0])
     modules = collections.defaultdict(lambda: [0, 0.0])
+    module_ops = collections.defaultdict(lambda: [0, 0.0])
     busy, gaps = [], []
     for ops_line, mod_line in device_lines:
-        spans = []
-        for name, s, e in _events(ops_line):
-            s, e = max(s, lo), min(e, hi)
-            if e <= s:
-                continue
-            spans.append((s, e))
-            ops[op_name(name)][0] += 1
-            ops[op_name(name)][1] += (e - s) * 1e-9
-        busy.append(union_length(spans) * 1e-9)
-        for name, s, e in (_events(mod_line) if mod_line else ()):
+        events = list(_events(mod_line)) if mod_line else []
+        mods = sorted(events, key=lambda ev: ev[1])
+        starts = [s for _, s, _ in mods]
+        for name, s, e in events:
             s, e = max(s, lo), min(e, hi)
             if e > s:
                 modules[name][0] += 1
                 modules[name][1] += (e - s) * 1e-9
-        gaps += _gaps(spans, lo, hi)
+        intervals = []
+        for name, s, e in _events(ops_line):
+            module = _module_of(starts, mods, s)
+            s, e = max(s, lo), min(e, hi)
+            if e <= s:
+                continue
+            intervals.append((s, e))
+            op, secs = op_name(name), (e - s) * 1e-9
+            for row in (ops[op], module_ops[(module, op)]):
+                row[0] += 1
+                row[1] += secs
+        busy.append(union_length(intervals) * 1e-9)
+        gaps += _gaps(intervals, lo, hi)
 
-    inner = [(n, s, e) for n, s, e in host_spans if n != WINDOW]
+    inner = [(n, s, e) for n, s, e in spans if n != WINDOW]
 
     def label(gs, ge):
-        best, cover = "host: outside any bench span", 0
-        for n, s, e in inner:
-            c = min(e, ge) - max(s, gs)
-            if c > cover:
-                best, cover = "host: " + n, c
-        return best
+        """The span that is innermost for the largest part of the gap:
+        at each instant the shortest span open then."""
+        cover = [(n, max(s, gs), min(e, ge), e - s) for n, s, e in inner
+                 if min(e, ge) > max(s, gs)]
+        if not cover:
+            return "host: outside any bench span"
+        cuts = sorted({t for _, s, e, _ in cover for t in (s, e)})
+        held = collections.defaultdict(int)
+        for a, b in zip(cuts, cuts[1:]):
+            open_ = [c for c in cover if c[1] <= a and b <= c[2]]
+            if open_:
+                held[min(open_, key=lambda c: c[3])[0]] += b - a
+        return "host: " + max(held, key=held.get)
 
     gaps.sort(key=lambda g: g[0] - g[1])
-    labelled = [(label(s, e), (e - s) * 1e-9) for s, e in gaps[:top_gaps]]
     return Reduced(window_s=(hi - lo) * 1e-9,
-                   busy_s=sum(busy) / max(len(busy), 1), devices=len(busy),
-                   ops=dict(ops), modules=dict(modules),
-                   idle_gaps=labelled)
+                   busy_s=sum(busy) / max(len(busy), 1),
+                   devices=len(busy), ops=dict(ops), modules=dict(modules),
+                   module_ops=dict(module_ops),
+                   idle_gaps=[(label(s, e), (e - s) * 1e-9)
+                              for s, e in gaps[:top_gaps]])
 
 
 def top_ops(red: Reduced, n: int = 10) -> list:

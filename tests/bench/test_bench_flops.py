@@ -99,3 +99,59 @@ def test_expert_gemm_least_counts_every_gemm():
                                        scaled=True)
     assert fwd == pytest.approx(want, rel=1e-12)
     assert both > 2.9 * fwd
+
+
+DEEPSEEK_PARTS = {"attention": 33_554_432, "dense_ffn": 67_239_936,
+                  "router": 131_072, "routed_experts": 51_904_512,
+                  "shared_experts": 17_301_504, "unembedding": 26_214_400}
+
+
+def _least_by_hand(ffns, layers, backward):
+    """expert_gemm_least_s's sum, term by term in its order."""
+    total = 0.0
+    for rows, g, f in ffns:
+        for k, n in ((2048, f), (2048, f), (f, 2048)):
+            total += flops.gemm_least_s(rows, k, n, g, 1, 1, 2, V5E,
+                                        scaled=True)
+            if backward:
+                total += flops.gemm_least_s(rows, n, k, g, 1, 1, 4, V5E,
+                                            scaled=True)
+                total += flops.gemm_least_s(rows, k, n, g, 2, 2, 4, V5E,
+                                            scaled=False, wgrad=True)
+    return total * layers
+
+
+def test_counts_without_a_router_width_are_bitwise_todays():
+    sz = _sizes("deepseek-moe-16b-l2")
+    assert sz["moe"]["router_experts"] == sz["moe"]["num_experts"] == 64
+    bare = {**sz, "moe": {k: v for k, v in sz["moe"].items()
+                          if k != "router_experts"}}
+    for s in (sz, bare):
+        assert flops.matmul_params(s) == DEEPSEEK_PARTS
+        assert flops.train_flops_per_token(s, 2048) == (
+            6 * 196_345_856 + 2 * 3 * 8192 * (2048 + 1) / 2)
+        for backward in (False, True):
+            # integer rows, as counted before a router width existed
+            assert flops.expert_gemm_least_s(
+                s, 4096, V5E, backward=backward) == _least_by_hand(
+                    [(4096 * 6, 64, 1408), (4096, 1, 2816)], 1, backward)
+
+
+def test_counts_of_a_held_share():
+    # 10 experts held of 60 scored, top-4: a token's 4 slots land on the
+    # held experts 4 x 10 / 60 times under balanced routing
+    sz = _sizes("qwen1.5-moe-a2.7b-l1")
+    sz["moe"].update(num_experts=10, router_experts=60)
+    parts = flops.matmul_params(sz)
+    assert parts["router"] == 2048 * 60
+    assert parts["routed_experts"] == 3 * 2048 * 1408 * 4 * 10 / 60
+    assert parts["routed_experts"] == 5_767_168
+    assert parts["shared_experts"] == 3 * 2048 * 5632
+    for backward in (False, True):
+        assert flops.expert_gemm_least_s(
+            sz, 6144, V5E, backward=backward) == pytest.approx(
+                _least_by_hand([(6144 * 4 * 10 / 60, 10, 1408),
+                                (6144, 1, 5632)], 1, backward), rel=1e-12)
+    # all held again: the full top_k
+    sz["moe"].update(num_experts=60)
+    assert flops.matmul_params(sz)["routed_experts"] == 3 * 2048 * 1408 * 4

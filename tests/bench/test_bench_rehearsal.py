@@ -1,6 +1,7 @@
-"""``bench/run.py`` as the benchmark command: a CPU rehearsal of a train
-and a serve cell prints a last line with exactly the contract's keys, and
-the command refuses to measure where there is no TPU or no program."""
+"""``bench/run.py`` as the benchmark command: a CPU rehearsal of every
+cell in ``BENCHMARK.json`` prints a last line with exactly the contract's
+keys (a train cell untraced, a serve cell traced, with its breakdown),
+and the command refuses to measure where there is no TPU or no program."""
 import json
 import os
 import shutil
@@ -11,6 +12,10 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from bench import spec  # noqa: E402
+
 KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 
 
@@ -21,11 +26,11 @@ def _run(args, cwd=ROOT, timeout=600):
                           timeout=timeout)
 
 
-@pytest.mark.parametrize("workload, trace, keys", [
-    ("dsmoe-train", "0", KEYS),
-    ("dsmoe-decode", "1", KEYS | {"breakdown"}),
-])
-def test_rehearsal_last_line(workload, trace, keys):
+@pytest.mark.parametrize(
+    "workload", [w["name"] for w in spec.benchmark()["workloads"]])
+def test_rehearsal_last_line(workload):
+    traced = spec.cell(workload).traffic["kind"] == "serve"
+    trace, keys = ("1", KEYS | {"breakdown"}) if traced else ("0", KEYS)
     p = _run(["--workload", workload, "--seed", str(2 ** 32 + 5),
               "--seconds", "0.3", "--trace", trace, "--cpu-rehearsal"])
     assert p.returncode == 0, p.stderr[-3000:]

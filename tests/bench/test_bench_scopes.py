@@ -14,7 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from bench import scopes, spec  # noqa: E402
+from bench import scopes, spec, trace_reduce  # noqa: E402
 from repro import scopes as program_scopes  # noqa: E402
 
 FIXTURE = os.path.join(ROOT, "bench", "fixtures",
@@ -120,7 +120,7 @@ def layered(tmp_path_factory):
         text = "".join(ln for ln in f if not ln.startswith("#"))
     path = tmp_path_factory.mktemp("trace") / "t.xplane.pb"
     path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
-    return scopes.reduce(str(path))
+    return trace_reduce.reduce(str(path), host_spans=scopes.host_spans())
 
 
 def test_module_ops_by_containment(layered):
@@ -154,10 +154,12 @@ def test_idle_gaps_take_the_innermost_span(layered):
 
 
 def _ctx(layered, kind, **counts):
-    chains = {k: scopes.hlo_chains(v, VOCAB).chains for k, v in HLO.items()}
-    return types.SimpleNamespace(kind=kind, traffic={"new": 5},
-                                 trace=layered, scopes=chains,
-                                 programs=dict(PROGRAMS), **counts)
+    parsed = {k: scopes.hlo_chains(v, VOCAB) for k, v in HLO.items()}
+    return types.SimpleNamespace(
+        kind=kind, traffic={"new": 5}, trace=layered,
+        scopes={k: p.chains for k, p in parsed.items()},
+        containers={k: p.containers for k, p in parsed.items()},
+        programs=dict(PROGRAMS), **counts)
 
 
 def test_a_while_and_its_body_count_once(layered):
@@ -196,7 +198,7 @@ def test_layer_metric_readers(layered):
 
 
 def test_attribution_table_and_unscoped_share(layered):
-    att = scopes.attribution(layered, PROGRAMS, HLO)
+    att = scopes.attribution(layered, _ctx(layered, "serve"))
     loop = "jit__decode_loop_impl(22)"
     tab = {k: (n, pytest.approx(s / NS)) for k, (n, s) in
            att["table"].items()}
